@@ -23,9 +23,6 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
-import textwrap
 
 from benchmarks import common
 from benchmarks.common import emit
@@ -85,13 +82,7 @@ def _sweep_record(shapes, cache_path=None, precond=True) -> dict:
         cache_env=cache_env,
         precond=bool(precond),
     )
-    proc = subprocess.run(
-        [sys.executable, "-c", textwrap.dedent(code)],
-        capture_output=True, text=True, timeout=1800, cwd=ROOT,
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(f"autotune sub-bench failed:\n{proc.stdout}\n{proc.stderr}")
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return common.run_cpu_child(code, timeout=1800, cwd=ROOT)
 
 
 def measure(toy: bool = False) -> dict:

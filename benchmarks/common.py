@@ -3,6 +3,9 @@ from __future__ import annotations
 
 import json
 import os
+import subprocess
+import sys
+import textwrap
 import time
 
 import jax
@@ -23,6 +26,25 @@ def time_fn(fn, *args, warmup: int = 1, iters: int = 3):
         times.append(time.perf_counter() - t0)
     times.sort()
     return times[len(times) // 2]
+
+
+def run_cpu_child(code: str, timeout: int, cwd: str | None = None) -> dict:
+    """Run a counting leg in a fresh interpreter pinned to the CPU and
+    return the JSON object on its last stdout line.
+
+    The legs count collective structure on 8 placeholder CPU devices, which
+    only a fresh process can create.  ``JAX_PLATFORMS=cpu`` keeps the child
+    off any accelerator: a chip belongs to one process, and the parent may
+    hold it.  Suites start these children before their own first JAX work.
+    """
+    proc = subprocess.run(
+        [sys.executable, "-c", textwrap.dedent(code)],
+        capture_output=True, text=True, timeout=timeout, cwd=cwd,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    )
+    if proc.returncode != 0:
+        raise RuntimeError(f"CPU sub-bench failed:\n{proc.stdout}\n{proc.stderr}")
+    return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
 def emit(name: str, us_per_call: float, derived: str = ""):

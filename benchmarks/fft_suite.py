@@ -37,11 +37,7 @@ overrides the path.
 """
 from __future__ import annotations
 
-import json
 import os
-import subprocess
-import sys
-import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -224,13 +220,7 @@ def _mesh_record(grid_shape, batch) -> dict:
         root=ROOT, root_src=os.path.join(ROOT, "src"),
         grid_shape=tuple(grid_shape), batch=int(batch),
     )
-    proc = subprocess.run(
-        [sys.executable, "-c", textwrap.dedent(code)],
-        capture_output=True, text=True, timeout=1800,
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(f"mesh sub-bench failed:\n{proc.stdout}\n{proc.stderr}")
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return common.run_cpu_child(code, timeout=1800)
 
 
 def _single_device(n: int) -> dict:

@@ -28,11 +28,7 @@ or the record schema drifts); ``BENCH_INTERP_OUT`` overrides the path.
 """
 from __future__ import annotations
 
-import json
 import os
-import subprocess
-import sys
-import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -167,21 +163,13 @@ def _mesh_record(grid_shape=(16, 16, 32)) -> dict:
     code = MESH_BODY.format(
         root=ROOT, root_src=os.path.join(ROOT, "src"), grid_shape=tuple(grid_shape)
     )
-    proc = subprocess.run(
-        [sys.executable, "-c", textwrap.dedent(code)],
-        capture_output=True, text=True, timeout=900,
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(f"mesh sub-bench failed:\n{proc.stdout}\n{proc.stderr}")
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return common.run_cpu_child(code, timeout=900)
 
 
 def measure(toy: bool = False) -> dict:
     sizes = (16,) if toy else (32, 64)
-    return {
-        "single_device": _single_device(sizes),
-        "mesh": _mesh_record(),
-    }
+    mesh = _mesh_record()  # the CPU child first, before this process's own JAX work
+    return {"single_device": _single_device(sizes), "mesh": mesh}
 
 
 def write_record(rec: dict, out: str) -> None:

@@ -10,10 +10,12 @@ Prints ``name,us_per_call,derived`` CSV rows.
 from __future__ import annotations
 
 import argparse
+import subprocess
 import sys
 import traceback
 
 from repro import telemetry
+from repro.launch.compile_cache import enable_compile_cache
 
 from benchmarks import (
     autotune_suite,
@@ -52,21 +54,30 @@ def main() -> None:
     ap.add_argument("--suite", action="append", default=[], choices=list(TABLES),
                     help="suite to run (repeatable); combined with positionals")
     args = ap.parse_args()
+    enable_compile_cache()
     which = list(args.suites) + list(args.suite) or list(TABLES)
     unknown = [w for w in which if w not in TABLES]
     if unknown:
         ap.error(f"unknown suite(s) {unknown}; choose from {list(TABLES)}")
 
-    # REPRO_TRACE=path.jsonl captures every bench row as telemetry events
-    telemetry.configure_from_env()
-    print("name,us_per_call,derived")
     failed = []
-    for name in which:
+    if len(which) > 1:
+        # one process per suite: the fft/interp/autotune suites start a CPU
+        # child before their own JAX work, which an earlier suite in this
+        # process would otherwise have done already (a chip has one owner)
+        for name in which:
+            proc = subprocess.run([sys.executable, "-m", "benchmarks.run", name])
+            if proc.returncode != 0:
+                failed.append(name)
+    else:
+        # REPRO_TRACE=path.jsonl captures every bench row as telemetry events
+        telemetry.configure_from_env()
+        print("name,us_per_call,derived")
         try:
-            TABLES[name]()
+            TABLES[which[0]]()
         except Exception:
             traceback.print_exc()
-            failed.append(name)
+            failed.append(which[0])
     if failed:
         print(f"FAILED: {failed}", file=sys.stderr)
         raise SystemExit(1)

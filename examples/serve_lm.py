@@ -12,6 +12,7 @@ import jax, jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_smoke_config
+from repro.launch.mesh import make_mesh
 from repro.models.common import ShardRules
 from repro.train.steps import build_model, make_serve_step
 
@@ -19,7 +20,7 @@ from repro.train.steps import build_model, make_serve_step
 def main():
     cfg = get_smoke_config("gemma3-1b")
     model = build_model(cfg)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     rules = ShardRules(mesh)
     params, _ = model.init(jax.random.PRNGKey(0), rules)
 

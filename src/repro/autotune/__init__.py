@@ -48,7 +48,7 @@ __all__ = [
 ]
 
 # default sentinels of the GNConfig perf knobs the resolver may fill
-_GN_DEFAULTS = {"interp_method": "ref", "plan_dtype": None, "field_dtype": None}
+_GN_DEFAULTS = {"interp_method": "auto", "plan_dtype": None, "field_dtype": None}
 
 
 def _ndev_of(ops) -> int:

@@ -39,15 +39,13 @@ def main(argv=None) -> int:
         return 0
     if args.sweep:
         import jax
-        import numpy as np
-        from jax.sharding import Mesh
 
         from repro.autotune.search import sweep_cell
         from repro.core.grid import make_grid
+        from repro.launch.mesh import make_mesh
 
         shape = tuple(int(x) for x in args.sweep.split(","))
-        devs = np.asarray(jax.devices())
-        mesh = Mesh(devs.reshape(1, devs.size), ("data", "model"))
+        mesh = make_mesh((1, jax.device_count()), ("data", "model"))
         rec = sweep_cell(make_grid(shape), mesh, beta=args.beta, cache=cache)
         print(json.dumps(rec, indent=2))
         return 0

@@ -300,8 +300,8 @@ def sweep_mesh_layouts(grid, devices=None, *, beta: float = 1e-2, halo: int = 4,
     — ``BENCH_autotune.json`` carries it next to the cell winners.
     """
     import jax
-    import numpy as np
-    from jax.sharding import Mesh
+
+    from repro.launch.mesh import make_mesh
 
     devices = list(devices if devices is not None else jax.devices())
     D = len(devices)
@@ -313,7 +313,7 @@ def sweep_mesh_layouts(grid, devices=None, *, beta: float = 1e-2, halo: int = 4,
     for p1, p2 in layouts:
         if grid.shape[0] % max(p1, 1) or grid.shape[1] % max(p2, 1):
             continue
-        mesh = Mesh(np.asarray(devices).reshape(p1, p2), ("data", "model"))
+        mesh = make_mesh((p1, p2), ("data", "model"), devices=devices)
         try:
             cost = _matvec_score(
                 grid, mesh, beta, {}, axes=("data", "model"), halo=halo,

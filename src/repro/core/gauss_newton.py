@@ -25,7 +25,9 @@ import numpy as np
 
 from repro.core import objective as obj
 from repro.core.grid import Grid
+from repro.core.planner import required_halo
 from repro.core.spectral import SpectralOps
+from repro.kernels import ops as kops
 from repro import telemetry
 from repro.resilience import health
 
@@ -42,7 +44,9 @@ class GNConfig:
     armijo_c1: float = 1e-4
     max_line_search: int = 10
     beta_continuation: tuple[float, ...] = ()  # e.g. (1e-1, 1e-2): warm starts
-    interp_method: str = "ref"  # "ref" | "pallas" | "auto"
+    # "auto": the Pallas kernel on TPU (the XLA gather there is ~27x slower
+    # at 128^3 and takes minutes to compile), the jnp gather elsewhere
+    interp_method: str = "auto"  # "ref" | "pallas" | "auto"
     # e.g. "bfloat16": pack InterpPlan weights.  Local-executor only — an
     # explicit interp= (the distributed path) carries its own setting via
     # DistContext(plan_dtype=...) / make_halo_interp(plan_dtype=...).
@@ -95,6 +99,41 @@ class NewtonLog(NamedTuple):
     # in-graph health code (``repro.resilience.health``): scalar for the
     # single solve, per-subject (S,) for the cohort step
     status: jnp.ndarray | int = 0
+    # ghost voxels the iteration's departure points reached: the Newton
+    # state's plan and the full (alpha = 1) trial step.  ``solve`` widens
+    # its own Pallas budget when this exceeds it
+    halo_need: jnp.ndarray | float = 0.0
+
+
+class Iterate(NamedTuple):
+    """A solver iterate: the velocity ``v`` and ``lap2 = Lap^2 v``, carried
+    exactly.  ``beta Lap^2 v`` recomputed from ``v``'s f32 values carries
+    their roundoff amplified by ``|k|^4`` — at 128^3 ~20% of the initial
+    gradient norm, so an f32 solve could never reach gtol 1e-2.  Every
+    solver path (``newton_iteration``, the cohort step, ``solve``,
+    ``solve_cohort``, the serving slots) carries this pair; PCG carries the
+    images of its directions the same way."""
+
+    v: jnp.ndarray
+    lap2: jnp.ndarray
+
+
+def with_lap2(v, ops, cold: bool = False) -> Iterate:
+    """The ``Iterate`` of ``v``.  A cold start (``v = 0``) has an exact zero
+    image; a warm start pays one transform, once."""
+    if cold:
+        return Iterate(v, jnp.zeros_like(v))
+    return Iterate(v, ops.biharmonic(v).astype(v.dtype))
+
+
+def _primary(t):
+    """The vector itself of a PCG iterate that may carry its image."""
+    return t.v if isinstance(t, Iterate) else t
+
+
+def _axpy(a, x, y):
+    """``y + a x`` over a vector or an ``Iterate``."""
+    return jax.tree_util.tree_map(lambda xi, yi: yi + a * xi, x, y)
 
 
 def pcg(
@@ -108,12 +147,17 @@ def pcg(
     """Matrix-free preconditioned conjugate gradients (lax.while_loop).
 
     Counts every Hessian matvec (the paper's Table V metric).
+
+    ``precond`` may return an ``Iterate`` ``(z, Lap^2 z)``.  The search
+    directions and the solution are linear combinations of the ``z``'s, so
+    PCG then carries their images too: ``matvec`` receives ``Iterate``
+    directions and ``x`` comes back as one.
     """
     bnorm = jnp.sqrt(inner(b, b))
-    x0 = jnp.zeros_like(b)
     r0 = b
     z0 = precond(r0)
-    rz0 = inner(r0, z0)
+    x0 = jax.tree_util.tree_map(jnp.zeros_like, z0)
+    rz0 = inner(r0, _primary(z0))
 
     def cond(c):
         x, r, p, rz, it = c
@@ -122,13 +166,13 @@ def pcg(
     def body(c):
         x, r, p, rz, it = c
         hp = matvec(p)
-        php = inner(p, hp)
+        php = inner(_primary(p), hp)
         alpha = rz / jnp.maximum(php, 1e-30)
-        x = x + alpha * p
+        x = _axpy(alpha, p, x)
         r = r - alpha * hp
         z = precond(r)
-        rz_new = inner(r, z)
-        p = z + (rz_new / jnp.maximum(rz, 1e-30)) * p
+        rz_new = inner(r, _primary(z))
+        p = _axpy(rz_new / jnp.maximum(rz, 1e-30), p, z)
         return (x, r, p, rz_new, it + 1)
 
     x, r, _, _, it = jax.lax.while_loop(cond, body, (x0, r0, z0, rz0, jnp.int32(0)))
@@ -153,6 +197,7 @@ def pcg_masked(
     (``x``/``r``/``p``/``rz`` masked in place, zero contribution from then
     on), and the loop ends when no subject is live.  Live trajectories are
     identical to independent ``pcg`` runs up to batched-transform roundoff.
+    ``precond`` may return ``Iterate`` pairs, carried as in ``pcg``.
 
     ``iters`` is per-subject ``(S,)`` — the paper's Table V matvec count as
     a billing meter: a retired (or never-active) subject accrues nothing.
@@ -162,10 +207,10 @@ def pcg_masked(
         return s.reshape(s.shape + (1,) * (b.ndim - 1))
 
     bnorm = jnp.sqrt(inner_per(b, b))
-    x0 = jnp.zeros_like(b)
     r0 = b
     z0 = precond(r0)
-    rz0 = inner_per(r0, z0)
+    x0 = jax.tree_util.tree_map(jnp.zeros_like, z0)
+    rz0 = inner_per(r0, _primary(z0))
     iters0 = jnp.zeros((b.shape[0],), jnp.int32)
 
     def live(r, iters):
@@ -179,14 +224,16 @@ def pcg_masked(
         x, r, p, rz, iters = c
         lv = live(r, iters)
         hp = matvec(p)
-        php = inner_per(p, hp)
+        php = inner_per(_primary(p), hp)
         alpha = jnp.where(lv, rz / jnp.maximum(php, 1e-30), 0.0)
-        x = x + bc(alpha) * p
+        x = _axpy(bc(alpha), p, x)
         r = r - bc(alpha) * hp
         z = precond(r)
-        rz_new = inner_per(r, z)
+        rz_new = inner_per(r, _primary(z))
         beta_cg = jnp.where(lv, rz_new / jnp.maximum(rz, 1e-30), 0.0)
-        p = jnp.where(bc(lv), z + bc(beta_cg) * p, p)
+        p = jax.tree_util.tree_map(
+            lambda zi, pi: jnp.where(bc(lv), zi + bc(beta_cg) * pi, pi), z, p
+        )
         rz = jnp.where(lv, rz_new, rz)
         return (x, r, p, rz, iters + lv.astype(jnp.int32))
 
@@ -211,17 +258,36 @@ def _tuned_cfg(cfg: GNConfig, grid: Grid, ops) -> GNConfig:
     return autotune.consult_gn(cfg, grid, ops)
 
 
-def _interp_fn(cfg: GNConfig):
-    from repro.kernels import ops as kops
+def _lap2_of_precond(r, beta):
+    """``Lap^2 z`` of the spectral preconditioner's output ``z = P r``,
+    exactly: P inverts ``beta Lap^2`` on every mode but the mean, which it
+    passes through (and ``Lap^2`` maps to zero).  In incompressible mode
+    ``r`` already lies in the Leray range, where the projection is the
+    identity.  No transform, so no roundoff amplified by ``|k|^4``."""
+    return (r - jnp.mean(r, axis=(-3, -2, -1), keepdims=True)) / beta
 
+
+def _halo_need(plan, v, grid: Grid):
+    """Ghost voxels a velocity's transports reach: the plan's bound, or —
+    when its departure solve was itself cut short (NaN) — the Euler
+    predictor's reach ``dt max|v| / h``, which bounds it."""
+    need = required_halo(plan)
+    h = jnp.asarray(grid.spacing, need.dtype).reshape(3, 1, 1, 1)
+    reach = jnp.ceil(plan.dt * jnp.max(jnp.abs(v) / h))
+    return jnp.where(jnp.isnan(need), reach, need)
+
+
+def _interp_fn(cfg: GNConfig, grid: Grid):
     # plan-aware executor: core.planner.make_plan caches an InterpPlan per
     # departure field through it, so every PCG Hessian matvec / line-search
-    # transport of an iteration reuses precomputed interpolation weights
-    return kops.make_interp(method=cfg.interp_method, plan_dtype=cfg.plan_dtype)
+    # transport of an iteration reuses precomputed interpolation weights;
+    # the Pallas path's ghost budget follows the grid and the time step
+    return kops.make_interp(method=cfg.interp_method, plan_dtype=cfg.plan_dtype,
+                            halo=kops.ghost_budget(grid.shape, cfg.n_t))
 
 
 def newton_iteration(
-    v: jnp.ndarray,
+    it: Iterate,
     g0_forcing: jnp.ndarray,
     prob: obj.Problem,
     ops: SpectralOps,
@@ -229,7 +295,8 @@ def newton_iteration(
     interp=None,
     precond=None,
 ):
-    """One globalized inexact Gauss-Newton step.  Returns (v_new, NewtonLog).
+    """One globalized inexact Gauss-Newton step from the ``Iterate`` ``it``.
+    Returns (Iterate, NewtonLog).
 
     ``g0_forcing`` is the Eisenstat-Walker *forcing* reference only — the
     denominator in ``eta = min(eta_max, sqrt(gnorm / g0_forcing))``.  It is
@@ -258,23 +325,37 @@ def newton_iteration(
     spectral preconditioner: the safeguard direction only needs descent, and
     a custom factory may be arbitrarily expensive (XLA's select evaluates
     both ``jnp.where`` operands).
+
+    The regularization terms of the gradient and of every Hessian matvec
+    read the carried ``Lap^2`` images (``Iterate``).  The spectral
+    preconditioner's image is exact and free (``_lap2_of_precond``); a
+    custom factory's output is transformed once per application.
     """
-    interp = interp or _interp_fn(cfg)
+    v, lap2 = it
+    interp = interp or _interp_fn(cfg, prob.grid)
     grid = prob.grid
-    state = obj.newton_state(v, prob, ops, interp)
+    state = obj.newton_state(v, prob, ops, interp, lap2=lap2)
     gnorm = jnp.sqrt(grid.norm_sq(state.g))
 
     # ---- Newton step: PCG on H dv = -g with (beta Lap^2)^{-1} preconditioner
     def matvec(p):
-        if cfg.gauss_newton:
-            return obj.gn_hessian_matvec(p, state, prob, ops, interp)
-        return obj.full_hessian_matvec(p, state, prob, ops, interp)
+        hess = obj.gn_hessian_matvec if cfg.gauss_newton else obj.full_hessian_matvec
+        return hess(p.v, state, prob, ops, interp, lap2=p.lap2)
 
     def spectral_precond(r):
         # single coalesced ride pair: P (beta Lap^2)^{-1} r
         return ops.precond_project(r, prob.beta, prob.incompressible)
 
-    precond = spectral_precond if precond is None else precond(state, prob)
+    if precond is None:
+        precond = spectral_precond
+
+        def image(r, z):
+            return _lap2_of_precond(r, prob.beta)
+    else:
+        precond = precond(state, prob)
+
+        def image(r, z):
+            return ops.biharmonic(z)
 
     # Critical-accumulation guard: the PCG recursion runs in >= f32 even when
     # ``field_dtype`` stores fields in bf16.  Casting the rhs and the
@@ -286,7 +367,8 @@ def newton_iteration(
     base_precond = precond
 
     def wide_precond(r):
-        return base_precond(r).astype(ct)
+        z = base_precond(r).astype(ct)
+        return Iterate(z, image(r, z).astype(ct))
 
     eta = jnp.minimum(cfg.eta_max, jnp.sqrt(gnorm / jnp.maximum(g0_forcing, 1e-30)))
     rhs = -state.g
@@ -294,7 +376,7 @@ def newton_iteration(
         rhs = ops.leray(rhs)
     rhs = rhs.astype(ct)
     sol = pcg(matvec, rhs, wide_precond, grid.inner, eta, cfg.max_cg)
-    dv = sol.x
+    dv, ldv = sol.x
     if prob.incompressible:
         dv = ops.leray(dv).astype(ct)
 
@@ -302,11 +384,12 @@ def newton_iteration(
     gdv = grid.inner(state.g, dv)
     # fall back to steepest descent if PCG returned a non-descent direction
     dv = jnp.where(gdv < 0, dv, -spectral_precond(state.g).astype(ct))
+    ldv = jnp.where(gdv < 0, ldv, -_lap2_of_precond(state.g, prob.beta).astype(ct))
     gdv = jnp.minimum(gdv, grid.inner(state.g, dv))
 
     def j_of(vv):
-        jval, _ = obj.evaluate_objective(vv, prob, ops, interp)
-        return jval
+        jval, (_, _, _, plan) = obj.evaluate_objective(vv, prob, ops, interp)
+        return jval, _halo_need(plan, vv, grid)
 
     def ls_cond(c):
         alpha, jnew, it = c
@@ -316,10 +399,10 @@ def newton_iteration(
     def ls_body(c):
         alpha, _, it = c
         alpha = alpha * 0.5
-        return (alpha, j_of(v + alpha * dv), it + 1)
+        return (alpha, j_of(v + alpha * dv)[0], it + 1)
 
     alpha0 = jnp.float32(1.0)
-    j1 = j_of(v + alpha0 * dv)
+    j1, need1 = j_of(v + alpha0 * dv)
     alpha, j_new, ls_it = jax.lax.while_loop(ls_cond, ls_body, (alpha0, j1, jnp.int32(0)))
     accepted = j_new < state.j_val
     v_new = jnp.where(accepted, v + alpha * dv, v)
@@ -332,11 +415,12 @@ def newton_iteration(
         j_val=state.j_val,
         j_new=j_new,
         gnorm=gnorm,
-        pcg_x=sol.x,
+        pcg_x=sol.x.v,
         pcg_rel=sol.rel_res,
         accepted=accepted,
     )
-    v_new = health.freeze(v_new, v, status)
+    lap2_new = jnp.where(accepted, lap2 + alpha * ldv, lap2)
+    it_new = Iterate(health.freeze(v_new, v, status), health.freeze(lap2_new, lap2, status))
 
     log = NewtonLog(
         j_val=state.j_val,
@@ -347,8 +431,9 @@ def newton_iteration(
         step_len=jnp.where(accepted, alpha, 0.0),
         ls_iters=ls_it,
         status=status,
+        halo_need=jnp.maximum(_halo_need(state.plan, v, grid), need1),
     )
-    return v_new, log
+    return it_new, log
 
 
 def solve(
@@ -380,11 +465,20 @@ def solve(
     gradient norm (first call uses a tiny sentinel, i.e. ``eta = eta_max``),
     so warm stages keep loose inner solves rather than inheriting the tight
     ``gnorm/g0_ref`` ratio and over-solving PCG.
+
+    Without ``interp`` the solver builds its own executor, whose Pallas
+    ghost budget follows the grid and ``n_t`` (``kops.ghost_budget``).  An
+    iteration whose departure points reach beyond it (``NewtonLog.halo_need``)
+    is run again from the same iterate with a widened budget (the gather
+    past ``kops.MAX_HALO``), so the solve follows the path of an unbounded
+    interpolation.  A caller's ``interp`` keeps its own budget policy.
     """
     cfg = _tuned_cfg(cfg, grid, ops)
     ops = ops or SpectralOps(grid, field_dtype=cfg.field_dtype)
     v = v0 if v0 is not None else jnp.zeros((3,) + grid.shape, grid.dtype)
-    interp = interp or _interp_fn(cfg)
+    cur = with_lap2(v, ops, cold=v0 is None)
+    own_interp = interp is None
+    interp = interp or _interp_fn(cfg, grid)
 
     betas = tuple(cfg.beta_continuation) + (cfg.beta,)
     history: list[dict] = []
@@ -404,21 +498,33 @@ def solve(
             n_t=cfg.n_t,
             incompressible=cfg.incompressible,
         )
-        step_fn = jax.jit(
-            partial(
-                newton_iteration, prob=prob, ops=ops, cfg=cfg, interp=interp, precond=precond
-            )
-        )
+        # the images are arguments, not closed-over constants: baked into
+        # the program they would make every image pair a new compilation
+        def make_step(interp, prob=prob):
+            def step(it_, g_, rho_R_, rho_T_):
+                return newton_iteration(
+                    it_, g_, prob._replace(rho_R=rho_R_, rho_T=rho_T_),
+                    ops=ops, cfg=cfg, interp=interp, precond=precond,
+                )
+
+            return jax.jit(step)
+
+        step_fn = make_step(interp)
         # convergence reference: g0_ref if supplied, else this stage's first
         # gradient norm; forcing reference: ALWAYS the stage's first gradient
         # norm (sentinel 1e-30 on the first call -> eta = eta_max).
         g0 = None if g0_ref is None else jnp.float32(g0_ref)
         g_forcing = None
-        for it in range(cfg.max_newton):
+        it = 0
+        while it < cfg.max_newton:
             with telemetry.span("gn.newton_iter", beta=float(beta), iter=it) as sp:
-                v, log = sp.sync(
-                    step_fn(v, g_forcing if g_forcing is not None else jnp.float32(1e-30))
-                )
+                g_f = g_forcing if g_forcing is not None else jnp.float32(1e-30)
+                nxt, log = sp.sync(step_fn(cur, g_f, rho_R, rho_T))
+            wider = own_interp and interp.widened(float(log.halo_need), grid.shape)
+            if wider:  # the budget cut this step short: run it again, wider
+                interp, step_fn = wider, make_step(wider)
+                continue
+            cur = nxt
             if g_forcing is None:
                 g_forcing = log.gnorm
             if g0 is None:
@@ -473,6 +579,7 @@ def solve(
                 break
             if rec["rel_gnorm"] <= cfg.gtol or rec["step"] == 0.0:
                 break
+            it += 1
         if health.is_failure(status_code):
             break
 
@@ -498,7 +605,10 @@ def solve(
         )
     )
     return {
-        "v": v,
+        "v": cur.v,
+        # the carried Lap^2 v: what the solver's gradient reads; a transform
+        # of v's f32 values is noisier
+        "lap2_v": cur.lap2,
         "history": history,
         "newton_iters": total_newton,
         "hessian_matvecs": total_matvecs,
@@ -513,7 +623,7 @@ def solve(
 
 
 def newton_iteration_cohort(
-    v: jnp.ndarray,
+    it: Iterate,
     g0_forcing: jnp.ndarray,
     active: jnp.ndarray,
     prob: obj.Problem,
@@ -521,7 +631,8 @@ def newton_iteration_cohort(
     cfg: GNConfig,
     interp=None,
 ):
-    """One masked Gauss-Newton step for a cohort ``v (S, 3, N..)``.
+    """One masked Gauss-Newton step for a cohort ``Iterate`` (``v`` and its
+    ``Lap^2`` image, both ``(S, 3, N..)``).
 
     Structurally ``newton_iteration`` with every scalar recursion made
     per-subject ``(S,)``: Eisenstat-Walker forcing, PCG termination
@@ -537,16 +648,17 @@ def newton_iteration_cohort(
     but ``pcg_masked``/line-search loops exit immediately, so retired
     subjects accrue no Hessian matvecs in the ``(S,)`` ``cg_iters`` meter.
     """
-    interp = interp or _interp_fn(cfg)
+    v, lap2 = it
+    interp = interp or _interp_fn(cfg, prob.grid)
     grid = prob.grid
-    state = obj.newton_state(v, prob, ops, interp)
+    state = obj.newton_state(v, prob, ops, interp, lap2=lap2)
     gnorm = jnp.sqrt(grid.norm_sq_per(state.g))
 
     def bc(s):  # (S,) -> (S,1,1,1,1)
         return s.reshape(s.shape + (1,) * (v.ndim - 1))
 
     def matvec(p):
-        return obj.gn_hessian_matvec(p, state, prob, ops, interp)
+        return obj.gn_hessian_matvec(p.v, state, prob, ops, interp, lap2=p.lap2)
 
     def spectral_precond(r):
         return ops.precond_project(r, prob.beta, prob.incompressible)
@@ -555,7 +667,8 @@ def newton_iteration_cohort(
     ct = jnp.promote_types(v.dtype, jnp.float32)
 
     def wide_precond(r):
-        return spectral_precond(r).astype(ct)
+        z = spectral_precond(r).astype(ct)
+        return Iterate(z, _lap2_of_precond(r, prob.beta).astype(ct))
 
     eta = jnp.minimum(cfg.eta_max, jnp.sqrt(gnorm / jnp.maximum(g0_forcing, 1e-30)))
     rhs = -state.g
@@ -563,13 +676,14 @@ def newton_iteration_cohort(
         rhs = ops.leray(rhs)
     rhs = rhs.astype(ct)
     sol = pcg_masked(matvec, rhs, wide_precond, grid.inner_per, eta, cfg.max_cg, active)
-    dv = sol.x
+    dv, ldv = sol.x
     if prob.incompressible:
         dv = ops.leray(dv).astype(ct)
 
     # per-subject steepest-descent safeguard
     gdv = grid.inner_per(state.g, dv)
     dv = jnp.where(bc(gdv < 0), dv, -spectral_precond(state.g).astype(ct))
+    ldv = jnp.where(bc(gdv < 0), ldv, -_lap2_of_precond(state.g, prob.beta).astype(ct))
     gdv = jnp.minimum(gdv, grid.inner_per(state.g, dv))
 
     def j_of(vv):
@@ -598,6 +712,7 @@ def newton_iteration_cohort(
     alpha, j_new, ls_it = jax.lax.while_loop(ls_cond, ls_body, (alpha0, j1, jnp.int32(0)))
     accepted = active & (j_new < state.j_val)
     v_new = jnp.where(bc(accepted), v + bc(alpha) * dv, v)
+    lap2_new = jnp.where(bc(accepted), lap2 + bc(alpha) * ldv, lap2)
 
     # per-subject in-graph health guard: the reductions keep the subjects
     # axis, and a sick subject's iterate is frozen so its NaNs never feed
@@ -608,13 +723,13 @@ def newton_iteration_cohort(
         j_val=state.j_val,
         j_new=j_new,
         gnorm=gnorm,
-        pcg_x=sol.x,
+        pcg_x=sol.x.v,
         pcg_rel=sol.rel_res,
         accepted=accepted,
         active=active,
         axes=tuple(range(1, v.ndim)),
     )
-    v_new = health.freeze(v_new, v, status)
+    it_new = Iterate(health.freeze(v_new, v, status), health.freeze(lap2_new, lap2, status))
 
     log = NewtonLog(
         j_val=state.j_val,
@@ -626,11 +741,11 @@ def newton_iteration_cohort(
         ls_iters=ls_it,  # shared lockstep halvings (scalar, not per-subject)
         status=status,
     )
-    return v_new, log
+    return it_new, log
 
 
 def _cohort_step(
-    v: jnp.ndarray,
+    it: Iterate,
     g0_forcing: jnp.ndarray,
     active: jnp.ndarray,
     beta: jnp.ndarray,
@@ -658,14 +773,15 @@ def _cohort_step(
         n_t=cfg.n_t,
         incompressible=cfg.incompressible,
     )
-    return newton_iteration_cohort(v, g0_forcing, active, prob, ops, cfg, interp)
+    return newton_iteration_cohort(it, g0_forcing, active, prob, ops, cfg, interp)
 
 
 def make_cohort_step(grid: Grid, cfg: GNConfig, ops: SpectralOps | None = None, interp=None):
     """Build the shared jitted cohort step for a (grid, mesh, cfg) bucket.
 
     The returned function has signature
-    ``step_fn(v, g0_forcing, active, beta, rho_R, rho_T)`` and is what
+    ``step_fn(it, g0_forcing, active, beta, rho_R, rho_T)`` (``it`` an
+    ``Iterate``) and is what
     ``solve_cohort`` iterates and what ``launch/reg_serve.py`` keeps hot in
     its executable cache across job admissions.
     """
@@ -675,7 +791,7 @@ def make_cohort_step(grid: Grid, cfg: GNConfig, ops: SpectralOps | None = None, 
         )
     cfg = _tuned_cfg(cfg, grid, ops)
     ops = ops or SpectralOps(grid, field_dtype=cfg.field_dtype)
-    interp = interp or _interp_fn(cfg)
+    interp = interp or _interp_fn(cfg, grid)
     return jax.jit(partial(_cohort_step, grid=grid, cfg=cfg, ops=ops, interp=interp))
 
 
@@ -723,6 +839,7 @@ def solve_cohort(
         )
     S = rho_R.shape[0]
     v = v0 if v0 is not None else jnp.zeros((S, 3) + grid.shape, grid.dtype)
+    v = with_lap2(v, ops or SpectralOps(grid), cold=v0 is None)
     if step_fn is None:
         step_fn = make_cohort_step(grid, cfg, ops=ops, interp=interp)
     active0 = (
@@ -821,7 +938,7 @@ def solve_cohort(
     status_codes[act0_np & (status_codes == health.OK)] = health.MAX_NEWTON
 
     out = {
-        "v": v,
+        "v": v.v,
         "history": history,
         "newton_iters": [int(x) for x in newton_counts],
         "hessian_matvecs": [int(x) for x in cg_counts],

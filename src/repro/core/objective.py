@@ -133,7 +133,7 @@ def evaluate_objective(
 
 @telemetry.annotate("objective.newton_state")
 def newton_state(
-    v: jnp.ndarray, prob: Problem, ops: SpectralOps, interp=None
+    v: jnp.ndarray, prob: Problem, ops: SpectralOps, interp=None, lap2=None
 ) -> NewtonState:
     """Forward + adjoint solves, reduced gradient, and the matvec cache.
 
@@ -142,6 +142,8 @@ def newton_state(
     cached gradient series ``grad rho(t_k)`` is one batched ride over all
     time slices; in incompressible mode ``P b`` costs one more.  Cohort
     inputs (``v (S,3,N..)``) share all of those rides across subjects.
+    ``lap2`` supplies ``Lap^2 v`` exactly (``gauss_newton.with_lap2``); the
+    gradient then reads it instead of transforming ``v``.
     """
     cohort = v.ndim == 5
     fd = getattr(ops, "field_dtype", None)
@@ -150,7 +152,7 @@ def newton_state(
     # Parseval reduction (no Lap v inverse — 3 fewer inverse fields)
     with ops.batch() as sb:
         h_divv = None if prob.incompressible else sb.div(v)
-        h_regv = sb.reg_apply(v, prob.beta)
+        h_regv = sb.reg_apply(v, prob.beta) if lap2 is None else None
         h_reg_e = sb.reg_energy(v, prob.beta)
     plan = make_plan(
         v, prob.grid, ops, prob.n_t, prob.incompressible, interp,
@@ -174,7 +176,8 @@ def newton_state(
     # eq. (4): g = beta Lap^2 v + P b, with lam(1) = rho_R - rho(1);
     # beta Lap^2 v comes from stage A, so only P b can cost a transform.
     # (sanity: at v=0, <g,w> = <(rho_R-rho_T) grad rho_T, w> = dJ/deps.)
-    g = h_regv.get() + _project(ops, b, prob.incompressible)
+    reg_v = h_regv.get() if lap2 is None else prob.beta * lap2
+    g = reg_v + _project(ops, b, prob.incompressible)
 
     misfit = 0.5 * _norm_sq(prob.grid, rho1 - prob.rho_R, cohort)
     reg = h_reg_e.get()
@@ -198,6 +201,7 @@ def gn_hessian_matvec(
     prob: Problem,
     ops: SpectralOps,
     interp=None,
+    lap2=None,
 ) -> jnp.ndarray:
     """Gauss-Newton Hessian action, eq. (5) with the lambda terms dropped.
 
@@ -208,12 +212,21 @@ def gn_hessian_matvec(
     3-component combine (incompressible); compressible mode adds ``bt`` in
     real space and transforms only ``vt``.  Cohort states apply S
     independent Hessians to a ``(S, 3, N..)`` stack in the same rides.
+    ``lap2`` supplies ``Lap^2 vt`` exactly (PCG carries it): then only
+    ``P bt`` is transformed, and nothing at all when compressible.
     """
     rho1_t = semilag.transport_inc_state(vtilde, state.grad_rho_series, state.plan, interp)
     lamt_series = semilag.transport_inc_adjoint(-rho1_t, state.plan, interp)
     bt = semilag.time_integral_b(lamt_series, state.grad_rho_series, state.plan.dt)
     # eq. (5e): H vt = beta Lap^2 vt + P bt, with lam~(1) = -rho~(1);
     # the data block is the Gauss-Newton (J^T J) term — PSD (tested).
+    return _elliptic(vtilde, bt, prob, ops, lap2)
+
+
+def _elliptic(vtilde, bt, prob: Problem, ops: SpectralOps, lap2):
+    """``beta Lap^2 vt + P bt``, reading ``Lap^2 vt`` off ``lap2`` when given."""
+    if lap2 is not None:
+        return prob.beta * lap2 + _project(ops, bt, prob.incompressible)
     if prob.incompressible:
         return ops.reg_plus_project(vtilde, bt, prob.beta, True)
     return ops.reg_apply(vtilde, prob.beta) + bt
@@ -221,7 +234,8 @@ def gn_hessian_matvec(
 
 @telemetry.annotate("objective.full_hessian_matvec")
 def full_hessian_matvec(
-    vtilde: jnp.ndarray, state: NewtonState, prob: Problem, ops: SpectralOps, interp=None
+    vtilde: jnp.ndarray, state: NewtonState, prob: Problem, ops: SpectralOps, interp=None,
+    lap2=None,
 ) -> jnp.ndarray:
     """FULL Newton Hessian action — paper eq. (5) with every term.
 
@@ -255,6 +269,4 @@ def full_hessian_matvec(
     # second term of b~: int lam(t) grad rho~(t) dt
     grad_rho_t = jnp.swapaxes(h_grad.get(), 0, 1)  # (n_t+1, 3, N..)
     bt = bt + semilag.time_integral_b(state.lam_series, grad_rho_t, state.plan.dt)
-    if prob.incompressible:
-        return ops.reg_plus_project(vtilde, bt, prob.beta, True)
-    return ops.reg_apply(vtilde, prob.beta) + bt
+    return _elliptic(vtilde, bt, prob, ops, lap2)

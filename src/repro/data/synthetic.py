@@ -44,16 +44,25 @@ def paper_velocity_divfree(grid: Grid, amplitude: float = 1.0) -> jnp.ndarray:
     )
 
 
-def synthetic_problem(n, n_t: int = 4, incompressible: bool = False, amplitude: float = 1.0):
-    """Build (rho_R, rho_T, v_star, grid) with rho_R = forward-transported rho_T."""
-    grid = make_grid(n)
-    ops = SpectralOps(grid)
+def synthetic_problem(n, n_t: int = 4, incompressible: bool = False, amplitude: float = 1.0,
+                      ctx=None):
+    """Build (rho_R, rho_T, v_star, grid) with rho_R = forward-transported rho_T.
+
+    With a ``DistContext`` the fields are built pencil-sharded and
+    transported on its mesh, so a grid too large for one chip never has to
+    fit on one."""
+    grid = make_grid(n) if ctx is None else ctx.grid
     rho_T = paper_template(grid)
     v_star = (
         paper_velocity_divfree(grid, amplitude) if incompressible else paper_velocity(grid, amplitude)
     )
-    plan = make_plan(v_star, grid, ops, n_t, incompressible)
-    rho_R = semilag.transport_state(rho_T, plan)[-1]
+    if ctx is None:
+        ops, interp = SpectralOps(grid), None
+    else:
+        ops, interp = ctx.ops, ctx.interp
+        rho_T, v_star = ctx.shard_scalar(rho_T), ctx.shard_vector(v_star)
+    plan = make_plan(v_star, grid, ops, n_t, incompressible, interp)
+    rho_R = semilag.transport_state(rho_T, plan, interp)[-1]
     return rho_R, rho_T, v_star, grid
 
 

@@ -45,11 +45,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import telemetry
 from repro.core.grid import Grid
+from repro.kernels import ops as kops
 from repro.kernels import ref
 from repro.kernels.tricubic import (
     tricubic_apply_pallas_padded,
@@ -117,49 +118,38 @@ def _exchange_ghosts(f: jnp.ndarray, *, a1, a2, p1, p2, lo, hi) -> jnp.ndarray:
     return _wrap_pad(fp, lo, hi, axis=nd - 1)
 
 
-def _interp_local_many(f, d, *, a1, a2, p1, p2, lo, hi, kernel="ref"):
+def _interp_local_many(f, d, *, a1, a2, p1, p2, lo, hi, kernel):
     """Batched per-device body: ``f`` (C, n1l, n2l, n3) rides ONE exchange.
 
     Scalar fields go through here too (C=1, reshaped by the dispatcher) —
-    one exchange/dispatch/fallback implementation for every arity.
+    one exchange/dispatch implementation for every arity.
 
-    ``kernel="pallas"`` dispatches the per-shard interpolation to the
-    VMEM-blocked Pallas kernel (``kernels/tricubic.py``): the ghost-extended
-    block IS the kernel's padded-field layout (``halo+1`` planes below,
-    ``halo+2`` above), so the exchange and the kernel compose with no copy.
-    Falls back to the ``kernels/ref.py`` gather when the shard shape has no
-    valid tile or the kernel would run interpreted off-TPU.
+    ``kernel`` (a ``kernels.ops.Resolved``) with method "pallas" dispatches
+    the per-shard interpolation to the VMEM-blocked Pallas kernel
+    (``kernels/tricubic.py``): the ghost-extended block IS the kernel's
+    padded-field layout (``halo+1`` planes below, ``halo+2`` above), so the
+    exchange and the kernel compose with no copy.
     """
     fp = _exchange_ghosts(f, a1=a1, a2=a2, p1=p1, p2=p2, lo=lo, hi=hi)
-    shape3 = f.shape[1:]
-    if kernel in ("pallas", "pallas_interpret"):
-        from repro.kernels.ops import _pick_tile
-
-        tile = _pick_tile(shape3)
-        if tile is not None:
-            return tricubic_displace_pallas_padded_many(
-                fp, d, tile=tile, halo=lo - 1, interpret=(kernel == "pallas_interpret")
-            )
+    if kernel.method == "pallas":
+        return tricubic_displace_pallas_padded_many(
+            fp, d, tile=kernel.tile, halo=lo - 1, interpret=kernel.interpret
+        )
     return ref.interp_apply_padded(fp, ref.make_interp_plan(d), lo)
 
 
-def _apply_local_many(f, ib, w, *, a1, a2, p1, p2, lo, hi, kernel="ref"):
+def _apply_local_many(f, ib, w, *, a1, a2, p1, p2, lo, hi, kernel):
     """Planned batched body: precomputed local operators, one exchange."""
     fp = _exchange_ghosts(f, a1=a1, a2=a2, p1=p1, p2=p2, lo=lo, hi=hi)
-    shape3 = f.shape[1:]
-    if kernel in ("pallas", "pallas_interpret"):
-        from repro.kernels.ops import _pick_tile
-
-        tile = _pick_tile(shape3)
-        if tile is not None:
-            return tricubic_apply_pallas_padded(
-                fp, ib, w, tile=tile, halo=lo - 1, interpret=(kernel == "pallas_interpret")
-            )
+    if kernel.method == "pallas":
+        return tricubic_apply_pallas_padded(
+            fp, ib, w, tile=kernel.tile, halo=lo - 1, interpret=kernel.interpret
+        )
     need = jnp.zeros((), jnp.float32)  # bound enforced by the checked wrapper
     return ref.interp_apply_padded(fp, ref.InterpPlan(ib=ib, w=w, halo_need=need), lo)
 
 
-def _interp_local_cohort(f, d, *, a1, a2, p1, p2, lo, hi, kernel="ref"):
+def _interp_local_cohort(f, d, *, a1, a2, p1, p2, lo, hi, kernel):
     """Cohort per-device body: ``f`` (C, S, n1l, n2l, n3) against per-subject
     displacements ``d`` (S, 3, n1l, n2l, n3).
 
@@ -174,24 +164,11 @@ def _interp_local_cohort(f, d, *, a1, a2, p1, p2, lo, hi, kernel="ref"):
     return ref.interp_apply_padded(fp, ref.make_interp_plan(d), lo)
 
 
-def _apply_local_cohort(f, ib, w, *, a1, a2, p1, p2, lo, hi, kernel="ref"):
+def _apply_local_cohort(f, ib, w, *, a1, a2, p1, p2, lo, hi, kernel):
     """Planned cohort body: precomputed per-subject operators, one exchange."""
     fp = _exchange_ghosts(f, a1=a1, a2=a2, p1=p1, p2=p2, lo=lo, hi=hi)
     need = jnp.zeros((), jnp.float32)  # bound enforced by the checked wrapper
     return ref.interp_apply_padded(fp, ref.InterpPlan(ib=ib, w=w, halo_need=need), lo)
-
-
-def _resolve_method(method: str) -> str:
-    """"auto" -> the Pallas kernel on TPU, the jnp gather elsewhere.
-
-    "pallas" forces the kernel (interpret mode off-TPU: correctness tests);
-    "ref" forces the gather.
-    """
-    if method == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "ref"
-    if method == "pallas" and jax.default_backend() != "tpu":
-        return "pallas_interpret"
-    return method
 
 
 def make_halo_interp(grid: Grid, mesh, axes=("data", "model"), halo: int = 4,
@@ -203,7 +180,8 @@ def make_halo_interp(grid: Grid, mesh, axes=("data", "model"), halo: int = 4,
     ``P(a1, a2, None)`` over the trailing space axes (leading channel dims
     replicated as a stack), ``disp`` a ``(3, N1, N2, N3)`` grid-unit
     displacement sharded ``P(None, a1, a2, None)`` with ``|disp| < halo``.
-    ``method`` picks the per-shard kernel (see ``_resolve_method``).
+    ``method`` picks the per-shard kernel for the shard's block shape
+    (``kernels.ops.resolve``: "auto" = Pallas on TPU, gather elsewhere).
 
     The returned callable carries ``make_plan`` / ``apply_plan`` so the
     solver's plan-once/apply-many path works on the mesh: plan construction
@@ -215,12 +193,12 @@ def make_halo_interp(grid: Grid, mesh, axes=("data", "model"), halo: int = 4,
     """
     a1, a2 = tuple(axes)
     p1, p2 = mesh_axes_size(mesh, a1), mesh_axes_size(mesh, a2)
-    n1, n2, _ = grid.shape
+    n1, n2, n3 = grid.shape
     if n1 % p1 or n2 % p2:
         raise ValueError(f"grid {grid.shape} not divisible by pencil mesh ({p1},{p2})")
-    kw = dict(a1=a1, a2=a2, p1=p1, p2=p2, lo=halo + 1, hi=halo + 2,
-              kernel=_resolve_method(method))
-    smkw = dict(mesh=mesh, check_rep=False)
+    kernel = kops.resolve(method, (n1 // p1, n2 // p2, n3))
+    kw = dict(a1=a1, a2=a2, p1=p1, p2=p2, lo=halo + 1, hi=halo + 2, kernel=kernel)
+    smkw = dict(mesh=mesh, check_vma=False)
     s_stack = P(None, a1, a2, None)
     s_w = P(None, None, a1, a2, None)
     sm4 = shard_map(partial(_interp_local_many, **kw), in_specs=(s_stack, s_stack),
@@ -255,6 +233,7 @@ def make_halo_interp(grid: Grid, mesh, axes=("data", "model"), halo: int = 4,
         out = sm_apply(fields.reshape((-1,) + fields.shape[-3:]), plan.ib, plan.w)
         return out.reshape(lead + out.shape[-3:])
 
+    interp.kernel = kernel
     interp.make_plan = partial(ref.make_interp_plan, dtype=plan_dtype)
     interp.apply_plan = apply_plan
     return interp
@@ -282,8 +261,6 @@ def make_checked_interp(halo_interp, mesh, axes, halo: int, on_overflow: str = "
     ``InterpPlan.halo_need`` (one max-reduction per Newton iteration, paid
     at plan-build time, instead of one per interp call).
     """
-    from repro.kernels.ops import max_displacement
-
     a1, a2 = tuple(axes)
     budget = jnp.float32(halo)
 
@@ -297,7 +274,7 @@ def make_checked_interp(halo_interp, mesh, axes, halo: int, on_overflow: str = "
         # ``halo_budget_exceeded`` counter event with the offending bound)
         telemetry.counter(
             "halo_budget_exceeded", echo=True,
-            required=float(n), budget=halo, mode=on_overflow,
+            required=float(n), budget=halo, mode="gather",
         )
 
     def warn_if(ok, need):
@@ -309,40 +286,40 @@ def make_checked_interp(halo_interp, mesh, axes, halo: int, on_overflow: str = "
         )
 
     def checked(field, disp):
-        need = jnp.ceil(max_displacement(disp))
+        need = jnp.ceil(kops.max_displacement(disp))
+        if on_overflow != "gather":
+            return kops.poison_beyond_halo(halo_interp(field, disp), need, halo)
         ok = need <= budget
         warn_if(ok, need)
-        if on_overflow == "gather":
-            return lax.cond(
-                ok,
-                halo_interp,
-                lambda f, d: lax.with_sharding_constraint(
-                    ref.tricubic_displace_many(f, d), out_sharding(field.ndim)
-                ),
-                field,
-                disp,
-            )
-        out = halo_interp(field, disp)
-        return out + jnp.where(ok, 0.0, jnp.nan).astype(out.dtype)
+        return lax.cond(
+            ok,
+            halo_interp,
+            lambda f, d: lax.with_sharding_constraint(
+                ref.tricubic_displace_many(f, d), out_sharding(field.ndim)
+            ),
+            field,
+            disp,
+        )
 
     def checked_apply(fields, plan: ref.InterpPlan):
+        if on_overflow != "gather":
+            return kops.poison_beyond_halo(halo_interp.apply_plan(fields, plan),
+                                           plan.halo_need, halo)
         ok = plan.halo_need <= budget
         warn_if(ok, plan.halo_need)
-        if on_overflow == "gather":
-            # ref.interp_apply wraps by global index arithmetic — exact for
-            # any displacement, so it is the planned gather fallback
-            return lax.cond(
-                ok,
-                halo_interp.apply_plan,
-                lambda f, p: lax.with_sharding_constraint(
-                    ref.interp_apply(f, p), out_sharding(fields.ndim)
-                ),
-                fields,
-                plan,
-            )
-        out = halo_interp.apply_plan(fields, plan)
-        return out + jnp.where(ok, 0.0, jnp.nan).astype(out.dtype)
+        # ref.interp_apply wraps by global index arithmetic — exact for
+        # any displacement, so it is the planned gather fallback
+        return lax.cond(
+            ok,
+            halo_interp.apply_plan,
+            lambda f, p: lax.with_sharding_constraint(
+                ref.interp_apply(f, p), out_sharding(fields.ndim)
+            ),
+            fields,
+            plan,
+        )
 
+    checked.kernel = halo_interp.kernel
     checked.make_plan = halo_interp.make_plan
     checked.apply_plan = checked_apply
     return checked

@@ -62,7 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import telemetry
@@ -183,11 +183,11 @@ class PencilFFT:
         kw = dict(a1=a1, a2=a2, p1=p1, p2=p2, chunk=self.chunk)
         self._fwd4 = shard_map(
             partial(_pipelined, _fwd_one, **kw), mesh=mesh,
-            in_specs=(spec_r,), out_specs=spec_k, check_rep=False,
+            in_specs=(spec_r,), out_specs=spec_k, check_vma=False,
         )
         self._inv4 = shard_map(
             partial(_pipelined, _inv_one, **kw), mesh=mesh,
-            in_specs=(spec_k,), out_specs=spec_r, check_rep=False,
+            in_specs=(spec_k,), out_specs=spec_r, check_vma=False,
         )
 
     # -- batching: leading dims are flattened into one batch axis so a single

@@ -1,10 +1,10 @@
 """Jitted dispatch layer over the interpolation kernels.
 
-``method="auto"`` picks the Pallas kernel on TPU when the semi-Lagrangian
-displacement bound fits the halo budget, and the pure-jnp oracle elsewhere
-(CPU/GPU, or when the planner reports an unbounded displacement).  On this
-CPU container the Pallas path runs in interpret mode (correctness only) —
-the solver keeps the oracle path hot so wall-clock tests stay fast.
+``method="auto"`` picks the Pallas kernel on TPU when the shape admits a
+lane-aligned tile, and the pure-jnp oracle elsewhere (CPU/GPU).  A TPU
+shape with no such tile runs the oracle with a warning; an explicit
+``"pallas"`` with no valid tile raises.  Off the TPU an explicit
+``"pallas"`` runs the kernel in interpret mode (correctness tests only).
 
 The first-class entry is ``make_interp``: an ``Interp`` executor implements
 the solver-wide interpolation protocol —
@@ -22,44 +22,121 @@ pencil mesh.
 from __future__ import annotations
 
 import functools
+import math
+import warnings
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.kernels import ref
 from repro.kernels.tricubic import (
+    LANES,
+    SUBLANES,
     tricubic_apply_pallas,
-    tricubic_displace_pallas,
     tricubic_displace_pallas_many,
 )
 
 
-def _pick_tile(shape: tuple[int, int, int]) -> tuple[int, int, int] | None:
-    def best(n, cands):
-        for c in cands:
-            if n % c == 0:
-                return c
-        return None
+def pick_tile(shape, native: bool) -> tuple[int, int, int] | None:
+    """Output tile of the Pallas kernel for a (N1, N2, N3) block, or None.
 
-    t1 = best(shape[0], (8, 4, 2, 1))
-    t2 = best(shape[1], (8, 4, 2, 1))
-    t3 = best(shape[2], (64, 32, 16, 8))
-    if t3 is None:
+    ``native`` (compiled for the chip): T3 = 128 lanes and T2 = 8 sublanes,
+    the only blocks Mosaic accepts.  Interpret mode takes any divisors.
+    """
+    def best(n, cands):
+        return next((c for c in cands if n % c == 0), None)
+
+    n1, n2, n3 = shape
+    t1 = best(n1, (8, 4, 2, 1))
+    if native:
+        t2, t3 = best(n2, (SUBLANES,)), best(n3, (LANES,))
+    else:
+        t2, t3 = best(n2, (8, 4, 2, 1)), best(n3, (64, 32, 16, 8))
+    if t2 is None or t3 is None:
         return None
     return (t1, t2, t3)
 
 
-def _resolve(method: str, shape3, tile):
-    """Single dispatch policy: "auto" -> the Pallas kernel on TPU, the jnp
-    oracle elsewhere; Pallas additionally needs a valid tile for the shape
-    (falls back to "ref" otherwise).  Returns (method, tile)."""
-    if method == "auto":
-        method = "pallas" if jax.default_backend() == "tpu" else "ref"
-    if method == "pallas":
-        tile = tile or _pick_tile(tuple(shape3))
-        if tile is None:
-            method = "ref"
-    return method, tile
+class Resolved(NamedTuple):
+    method: str  # "ref" | "pallas"
+    tile: tuple[int, int, int] | None
+    interpret: bool
+
+
+def resolve(method: str, shape3, tile=None) -> Resolved:
+    """Single dispatch policy for a (N1, N2, N3) block (a whole grid, or one
+    shard of the mesh path): "auto" -> the Pallas kernel on TPU, the jnp
+    oracle elsewhere.  Never falls back in silence: "auto" on a TPU shape
+    with no lane-aligned tile warns, an explicit "pallas" with none raises."""
+    on_tpu = jax.default_backend() == "tpu"
+    if method == "ref" or (method == "auto" and not on_tpu):
+        return Resolved("ref", None, False)
+    if method not in ("auto", "pallas"):
+        raise ValueError(f"unknown interpolation method {method!r}")
+    tile = tile or pick_tile(tuple(shape3), native=on_tpu)
+    if tile is None:
+        why = (f"no Pallas tile for block {tuple(shape3)}: the chip needs "
+               f"N2 % {SUBLANES} == 0 and N3 % {LANES} == 0")
+        if method == "pallas":
+            raise ValueError(why)
+        warnings.warn(why + "; interpolating with the jnp gather", stacklevel=3)
+        return Resolved("ref", None, False)
+    return Resolved("pallas", tuple(tile), not on_tpu)
+
+
+# ghost budget of the direct kernel entries (``tricubic_displace*``), in
+# voxels; the solver sizes its own with ``ghost_budget``
+DEFAULT_HALO = 8
+# peak speed (physical units on [0, 2 pi)^3) a derived budget covers: 1.5x
+# that of the paper's synthetic velocity
+BUDGET_SPEED = 1.5
+# widest budget the kernel is compiled with (AOT-checked for v5e at C=3 in
+# tests/test_tpu_compile.py); a wider need interpolates with the gather
+MAX_HALO = 16
+
+
+def ghost_budget(shape, n_t: int) -> int:
+    """Ghost-layer width, in voxels, for departure points of a velocity of
+    peak ``BUDGET_SPEED`` over one of ``n_t`` time steps on a ``shape``
+    grid of [0, 2 pi)^3: the CFL-style bound ``speed N / (2 pi n_t)`` on
+    the finest axis, at least 4.  8 at 128^3 and 16 at 256^3 with n_t 4."""
+    return max(4, math.ceil(BUDGET_SPEED * max(shape) / (2 * math.pi * n_t)))
+
+
+def _count_overflow(need, halo):
+    telemetry.counter("halo_budget_exceeded", echo=True, required=float(need),
+                      budget=halo, mode="error")
+
+
+def poison_beyond_halo(out, need, halo):
+    """NaN-poison ``out`` when ``need`` (ceil max |disp|) exceeds the ghost
+    budget ``halo``, counting a ``halo_budget_exceeded`` event: a kernel
+    fed a displacement beyond its ghost layer returns garbage, and NaNs stop
+    the line search / health guard instead.  No gather is compiled in: on
+    the TPU the XLA gather takes minutes to compile at 128^3."""
+    ok = need <= halo
+    jax.lax.cond(ok, lambda n: None, lambda n: jax.debug.callback(_count_overflow, n, halo),
+                 need)
+    return out + jnp.where(ok, 0.0, jnp.nan).astype(out.dtype)
+
+
+def _per_subject(kernel, fields, *operands):
+    """A single-subject kernel over a cohort: ``fields (..., S, N..)``
+    against per-subject ``operands`` ``(S, ...)``, one call per subject."""
+    outs = [kernel(fields[..., s, :, :, :], *(x[s] for x in operands))
+            for s in range(operands[0].shape[0])]
+    return jnp.stack(outs, axis=-4)
+
+
+def _pallas_displace(fields, disp, rs, halo):
+    shape3 = fields.shape[-3:]
+    out = tricubic_displace_pallas_many(
+        fields.reshape((-1,) + shape3), disp, tile=rs.tile, halo=halo,
+        interpret=rs.interpret,
+    )
+    return out.reshape(fields.shape)
 
 
 def tricubic_displace(
@@ -67,20 +144,15 @@ def tricubic_displace(
     disp: jnp.ndarray,
     *,
     method: str = "auto",
-    halo: int = 4,
+    halo: int = DEFAULT_HALO,
     tile: tuple[int, int, int] | None = None,
 ) -> jnp.ndarray:
     """field (N1,N2,N3) sampled at x + disp; disp (3,N1,N2,N3), grid units."""
-    method, tile = _resolve(method, field.shape, tile)
-    if method == "ref":
+    rs = resolve(method, field.shape, tile)
+    if rs.method == "ref":
         return ref.tricubic_displace(field, disp)
-    interpret = jax.default_backend() != "tpu"
-    return tricubic_displace_pallas(field, disp, tile=tile, halo=halo, interpret=interpret)
-
-
-def tricubic_displace_vec(field: jnp.ndarray, disp: jnp.ndarray, **kw) -> jnp.ndarray:
-    """Vector/stacked fields: (C, N1,N2,N3)."""
-    return jax.vmap(lambda f: tricubic_displace(f, disp, **kw))(field)
+    return poison_beyond_halo(_pallas_displace(field, disp, rs, halo),
+                              jnp.ceil(max_displacement(disp)), halo)
 
 
 def tricubic_displace_many(
@@ -88,47 +160,42 @@ def tricubic_displace_many(
     disp: jnp.ndarray,
     *,
     method: str = "auto",
-    halo: int = 4,
+    halo: int = DEFAULT_HALO,
     tile: tuple[int, int, int] | None = None,
 ) -> jnp.ndarray:
     """Batched multi-field entry: ``fields`` (..., N1,N2,N3), leading dims
     are channels sharing one weight construction / one kernel launch.
 
     A cohort displacement ``disp (S, 3, N..)`` pairs subject ``s`` with the
-    ``-4`` axis of ``fields`` (``(..., S, N..)``); the per-subject gathers
-    run on the jnp oracle (the Pallas kernel is single-subject)."""
-    shape3 = fields.shape[-3:]
-    lead = fields.shape[:-3]
+    ``-4`` axis of ``fields`` (``(..., S, N..)``); the Pallas path runs one
+    kernel call per subject."""
+    rs = resolve(method, fields.shape[-3:], tile)
+    if rs.method == "ref":
+        return ref.tricubic_displace_many(fields, disp)
     if disp.ndim == 5:  # cohort: per-subject departure fields
-        return ref.tricubic_displace_many(fields, disp)
-    method, tile = _resolve(method, shape3, tile)
-    if method == "ref":
-        return ref.tricubic_displace_many(fields, disp)
-    interpret = jax.default_backend() != "tpu"
-    out = tricubic_displace_pallas_many(
-        fields.reshape((-1,) + shape3), disp, tile=tile, halo=halo, interpret=interpret
-    )
-    return out.reshape(lead + shape3)
+        out = _per_subject(lambda f, d: _pallas_displace(f, d, rs, halo), fields, disp)
+    else:
+        out = _pallas_displace(fields, disp, rs, halo)
+    return poison_beyond_halo(out, jnp.ceil(max_displacement(disp)), halo)
 
 
 class Interp:
     """Plan-aware single-device interpolation executor (see module docstring).
 
-    ``method``/``halo``/``tile`` follow ``tricubic_displace``; the Pallas
-    budget ``halo`` also caps plan displacements on that path (checked by
-    the caller via ``core.planner.required_halo``).  ``plan_dtype`` packs
-    the cached ``InterpPlan`` weights (e.g. ``jnp.bfloat16`` halves the
-    plan's memory; contraction stays f32 — see ``ref.make_interp_plan``).
+    ``method``/``halo``/``tile`` follow ``tricubic_displace``: on the Pallas
+    path a displacement beyond ``halo`` NaN-poisons the result
+    (``poison_beyond_halo``, read off the plan's cached ``halo_need``).
+    ``plan_dtype`` packs the cached ``InterpPlan`` weights (e.g.
+    ``jnp.bfloat16`` halves the plan's memory; contraction stays f32 — see
+    ``ref.make_interp_plan``).
     """
 
-    def __init__(self, method: str = "auto", halo: int = 4, tile=None, plan_dtype=None):
+    def __init__(self, method: str = "auto", halo: int = DEFAULT_HALO, tile=None,
+                 plan_dtype=None):
         self.method = method
         self.halo = halo
         self.tile = tile
         self.plan_dtype = plan_dtype
-
-    def _resolved(self, shape3):
-        return _resolve(self.method, shape3, self.tile)
 
     def __call__(self, field: jnp.ndarray, disp: jnp.ndarray) -> jnp.ndarray:
         if field.ndim == 3:
@@ -144,19 +211,45 @@ class Interp:
 
     def apply_plan(self, fields: jnp.ndarray, plan: ref.InterpPlan) -> jnp.ndarray:
         shape3 = fields.shape[-3:]
-        method, tile = self._resolved(shape3)
-        if method == "ref" or plan.ib.ndim == 5:  # cohort plans: oracle path
+        rs = resolve(self.method, shape3, self.tile)
+        if rs.method == "ref":
             return ref.interp_apply(fields, plan)
-        lead = fields.shape[:-3]
-        interpret = jax.default_backend() != "tpu"
-        out = tricubic_apply_pallas(
-            fields.reshape((-1,) + shape3), plan,
-            tile=tile, halo=self.halo, interpret=interpret,
-        )
-        return out.reshape(lead + shape3)
+
+        def kernel(f, ib, w):
+            out = tricubic_apply_pallas(
+                f.reshape((-1,) + shape3), ref.InterpPlan(ib, w, plan.halo_need),
+                tile=rs.tile, halo=self.halo, interpret=rs.interpret,
+            )
+            return out.reshape(f.shape)
+
+        if plan.ib.ndim == 5:  # cohort plan: one kernel call per subject
+            out = _per_subject(kernel, fields, plan.ib, plan.w)
+        else:
+            out = kernel(fields, plan.ib, plan.w)
+        return poison_beyond_halo(out, plan.halo_need, self.halo)
+
+    def widened(self, need: float, shape3) -> "Interp | None":
+        """The executor for a step whose departure points reached ``need``
+        voxels, or None when this one covers it (or runs no kernel): the
+        Pallas kernel with 1.5x the need as its budget, or — past
+        ``MAX_HALO`` — the exact jnp gather, with a warning."""
+        if not math.isfinite(need) or need <= self.halo:
+            return None
+        if resolve(self.method, shape3, self.tile).method != "pallas":
+            return None
+        halo = math.ceil(1.5 * need)
+        telemetry.counter("interp.halo_widened", required=float(need), budget=self.halo,
+                          widened_to=min(halo, MAX_HALO + 1))
+        if halo > MAX_HALO:
+            warnings.warn(f"departure points {need:g} voxels away exceed the Pallas "
+                          f"kernel's widest ghost budget ({MAX_HALO}); interpolating "
+                          "with the jnp gather", stacklevel=2)
+            return Interp("ref", plan_dtype=self.plan_dtype)
+        return Interp(self.method, halo=halo, tile=self.tile, plan_dtype=self.plan_dtype)
 
 
-def make_interp(method: str = "auto", halo: int = 4, tile=None, plan_dtype=None) -> Interp:
+def make_interp(method: str = "auto", halo: int = DEFAULT_HALO, tile=None,
+                plan_dtype=None) -> Interp:
     """Factory for the solver's ``interp=`` slots (kept for API symmetry
     with ``repro.dist.halo.make_halo_interp``)."""
     return Interp(method=method, halo=halo, tile=tile, plan_dtype=plan_dtype)

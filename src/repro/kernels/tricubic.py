@@ -14,17 +14,21 @@ of exactly that blocking:
     see core/planner.py), so one halo of width H+2 covers the whole
     4-point stencil of every query in the tile.
   * TPUs have no hardware gather, so the 4x4x4 stencil gather is recast
-    as dense **one-hot contractions**: per dimension we build a (P, W)
-    interpolation matrix A_d (4 cubic Lagrange weights scattered at the
-    stencil rows) and contract A_1 on the MXU, A_2/A_3 on the VPU.
-    This turns a scatter/gather-bound loop into systolic matmul work
-    (the "vectorization" item, in MXU form).
+    as a dense sweep over the R = 2H+4 window offsets each axis can reach:
+    per axis we build R one-hot weight planes (the cubic Lagrange weight of
+    each point at each offset, zero off its 4-point stencil) and contract
+    the window against them on the VPU — x3 offsets by lane rotations of
+    one window row, x2 offsets by sublane-shifted row loads, x1 offsets by
+    a loop over window planes.  Every query of a (T2, T3) slice is one
+    lane-dense vreg tile, so the whole contraction is exact f32 VPU work
+    with no gathers, reshapes or matmul precision loss.
 
-Layout: VMEM working set per tile is
-``W1*W2*W3*4B  (scratch) + P*W2*W3*4B (largest intermediate)`` with
-``W_d = T_d + 2H + 3`` and ``P = T2*T3`` points per x1-slice sub-block;
-defaults (tile 8x8x32, H=4) keep it under ~2 MB, MXU dims are padded by
-Mosaic.  Validated in interpret mode against kernels/ref.py.
+Layout: the output tile is (T1, T2, T3) with T3 on lanes; on the chip
+``T2 % 8 == 0`` and ``T3 % 128 == 0`` (``kernels.ops.pick_tile``).  The VMEM
+scratch holds the ghost-extended window ``(C, T1+R-1, T2+R-1, L3)`` with
+``L3 = T3+R-1`` rounded up to whole lanes — about 0.5 MB per channel at the
+default (8, 8, 128) tile and H=4.  Fields and plan weights are widened to
+f32 once on entry; the result is cast back to the field dtype.
 """
 from __future__ import annotations
 
@@ -37,271 +41,148 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ref import InterpPlan, lagrange_weights
 
+SUBLANES, LANES = 8, 128
 
-def _onehot_matrix(i0, wts, p, w):
-    """(P, W) one-hot interpolation matrix from stencil bases + weights.
 
-    ``i0`` (P,) f32 — base (offset -1 row) index of each query in the local
-    window; ``wts`` (4, P) — the cubic Lagrange weights to scatter.
+def _round_up(n, m):
+    return -(-n // m) * m
+
+
+def _onehot_plane(rel, wts, o):
+    """Weight of window offset ``o`` for every query: ``wts[k]`` where
+    ``rel + k == o`` (``rel`` = halo + stencil base, int32), else 0."""
+    plane = jnp.where(rel == o, wts[0], 0.0)
+    for k in range(1, 4):
+        plane = jnp.where(rel + k == o, wts[k], plane)
+    return plane
+
+
+def _contract_slice(scratch, planes, out_ref, s1, rel, wts, *, tile, halo):
+    """Interpolate the C channels of one x1-slice ``s1`` of the output tile.
+
+    ``rel[d]`` (T2, T3) int32 is the window offset of each query's first
+    stencil entry along axis d; ``wts[d]`` its 4 Lagrange weights (f32).
+    Only the offsets some query of the slice reaches are swept: a smooth
+    displacement spans a few offsets per axis, so the cost follows the
+    slice's spread of stencil bases, not the halo budget.
     """
-    rel = jax.lax.broadcasted_iota(jnp.float32, (p, w), 1) - i0[:, None]
-    a = (
-        wts[0][:, None] * (rel == -1.0)
-        + wts[1][:, None] * (rel == 0.0)
-        + wts[2][:, None] * (rel == 1.0)
-        + wts[3][:, None] * (rel == 2.0)
-    )
-    return a.astype(jnp.float32)
+    _, t2, t3 = tile
+    r = 2 * halo + 4
+    c, w2, lanes = scratch.shape[0], scratch.shape[2], scratch.shape[3]
+    for o in range(r):  # one-hot planes of axes 2 and 3, indexed dynamically
+        planes[0, o] = _onehot_plane(rel[1], wts[1], o)
+        planes[1, o] = _onehot_plane(rel[2], wts[2], o)
+    lo = [jnp.min(x) for x in rel]
+    hi = [jnp.max(x) + 4 for x in rel]
+    zero = jnp.zeros((t2, t3), jnp.float32)
+
+    def plane_a(a, acc):
+        slabs = [scratch[ch, s1 + a] for ch in range(c)]  # (W2, L3)
+
+        def row_b(b, h):
+            # rows b .. b+T2 of the window plane: a sublane rotation (loads
+            # at a dynamic unaligned sublane offset do not lower)
+            shift = (w2 - b) % w2
+            rows = [pltpu.roll(x, shift, 0)[:t2] for x in slabs]
+
+            def lane_o(o3, g):
+                w = planes[1, o3]
+                shift = (lanes - o3) % lanes
+                return tuple(g[ch] + w * pltpu.roll(rows[ch], shift, 1)[:, :t3]
+                             for ch in range(c))
+
+            g = jax.lax.fori_loop(lo[2], hi[2], lane_o, (zero,) * c)
+            w = planes[0, b]
+            return tuple(h[ch] + w * g[ch] for ch in range(c))
+
+        h = jax.lax.fori_loop(lo[1], hi[1], row_b, (zero,) * c)
+        w = _onehot_plane(rel[0], wts[0], a)
+        return tuple(acc[ch] + w * h[ch] for ch in range(c))
+
+    acc = jax.lax.fori_loop(lo[0], hi[0], plane_a, (zero,) * c)
+    for ch in range(c):
+        out_ref[ch, pl.ds(s1, 1), :, :] = acc[ch][None].astype(out_ref.dtype)
 
 
-def _kernel(fpad_hbm, disp_ref, out_ref, scratch, sem, *, tile, halo):
-    t1, t2, t3 = tile
-    w1 = t1 + 2 * halo + 3
-    w2 = t2 + 2 * halo + 3
-    w3 = t3 + 2 * halo + 3
+def _stage_window(fpad_hbm, scratch, sem, *, tile):
+    """DMA the ghost-extended input window of this grid step into VMEM."""
     i, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-
-    # --- HBM -> VMEM: input tile + halo (padded array origin = -(halo+1)) ---
+    w = scratch.shape[1:]
     cp = pltpu.make_async_copy(
-        fpad_hbm.at[pl.ds(i * t1, w1), pl.ds(j * t2, w2), pl.ds(k * t3, w3)],
+        fpad_hbm.at[:, pl.ds(i * tile[0], w[0]), pl.ds(j * tile[1], w[1]),
+                    pl.ds(k * tile[2], w[2])],
         scratch,
         sem,
     )
     cp.start()
     cp.wait()
 
-    fld = scratch[...].astype(jnp.float32)
-    flat23 = fld.reshape(w1, w2 * w3)
 
-    def one_slice(s1, _):
-        # queries of the x1-slice s1: local coords inside the scratch tile
-        d1 = disp_ref[0, s1, :, :].astype(jnp.float32).reshape(-1)  # (P,)
-        d2 = disp_ref[1, s1, :, :].astype(jnp.float32).reshape(-1)
-        d3 = disp_ref[2, s1, :, :].astype(jnp.float32).reshape(-1)
-        p = d1.shape[0]
+def _kernel_displace(fpad_hbm, disp_ref, out_ref, scratch, planes, sem, *, tile, halo):
+    """Stencil bases + weights built in-kernel from the displacement."""
+    _stage_window(fpad_hbm, scratch, sem, tile=tile)
 
-        base2 = jax.lax.broadcasted_iota(jnp.float32, (t2, t3), 0).reshape(-1)
-        base3 = jax.lax.broadcasted_iota(jnp.float32, (t2, t3), 1).reshape(-1)
-        off = jnp.float32(halo + 1)
-        q1 = s1.astype(jnp.float32) + off + d1
-        q2 = base2 + off + d2
-        q3 = base3 + off + d3
+    def one_slice(s1, carry):
+        rel, wts = [], []
+        for d in range(3):
+            q = disp_ref[d, s1, :, :]
+            ib = jnp.floor(q)
+            rel.append(ib.astype(jnp.int32) + halo)
+            wts.append(list(lagrange_weights(q - ib)))
+        _contract_slice(scratch, planes, out_ref, s1, rel, wts, tile=tile, halo=halo)
+        return carry
 
-        def interp_matrix(q, w):
-            i0 = jnp.floor(q)
-            wts = lagrange_weights(q - i0)  # (4, P)
-            return _onehot_matrix(i0, wts, p, w)  # (P, W)
-
-        a1 = interp_matrix(q1, w1)
-        a2 = interp_matrix(q2, w2)
-        a3 = interp_matrix(q3, w3)
-
-        # MXU: contract dim-1  (P, W1) @ (W1, W2*W3) -> (P, W2*W3)
-        s = jnp.dot(a1, flat23, preferred_element_type=jnp.float32)
-        s = s.reshape(p, w2, w3)
-        # VPU: contract dim-2 and dim-3
-        s = jnp.sum(a2[:, :, None] * s, axis=1)  # (P, W3)
-        res = jnp.sum(a3 * s, axis=1)  # (P,)
-        out_ref[pl.ds(s1, 1), :, :] = res.reshape(1, t2, t3).astype(out_ref.dtype)
-        return _
-
-    jax.lax.fori_loop(0, t1, one_slice, 0)
+    jax.lax.fori_loop(0, tile[0], one_slice, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "halo", "interpret"))
-def tricubic_displace_pallas_padded(
-    fpad: jnp.ndarray,
-    disp: jnp.ndarray,
-    *,
-    tile: tuple[int, int, int] = (8, 8, 32),
-    halo: int = 4,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Kernel entry for an ALREADY ghost-extended field.
-
-    ``fpad`` is the (N1+2H+3, N2+2H+3, N3+2H+3) block with ``halo+1`` planes
-    below and ``halo+2`` above each axis — exactly the layout produced both
-    by ``jnp.pad(mode="wrap")`` (single device) and by the multi-hop
-    ``ppermute`` ghost exchange in ``repro.dist.halo`` (per-shard block), so
-    the distributed path dispatches here without an extra copy.
-    """
-    pad = 2 * halo + 3
-    n1, n2, n3 = (s - pad for s in fpad.shape)
-    t1, t2, t3 = tile
-    assert n1 % t1 == 0 and n2 % t2 == 0 and n3 % t3 == 0, ((n1, n2, n3), tile)
-    w = (t1 + 2 * halo + 3, t2 + 2 * halo + 3, t3 + 2 * halo + 3)
-    grid = (n1 // t1, n2 // t2, n3 // t3)
-    kern = functools.partial(_kernel, tile=tile, halo=halo)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),  # stays in HBM; DMA'd manually
-            pl.BlockSpec((3, t1, t2, t3), lambda i, j, k: (0, i, j, k)),
-        ],
-        out_specs=pl.BlockSpec((t1, t2, t3), lambda i, j, k: (i, j, k)),
-        out_shape=jax.ShapeDtypeStruct((n1, n2, n3), fpad.dtype),
-        scratch_shapes=[pltpu.VMEM(w, fpad.dtype), pltpu.SemaphoreType.DMA],
-        interpret=interpret,
-    )(fpad, disp)
-
-
-@functools.partial(jax.jit, static_argnames=("tile", "halo", "interpret"))
-def tricubic_displace_pallas(
-    field: jnp.ndarray,
-    disp: jnp.ndarray,
-    *,
-    tile: tuple[int, int, int] = (8, 8, 32),
-    halo: int = 4,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Evaluate ``field`` at ``x + disp`` (grid units), |disp| <= halo.
-
-    field: (N1, N2, N3) f32/bf16; disp: (3, N1, N2, N3).
-    Wrap-around periodicity is materialized once by pre-padding the field
-    by (halo+1, halo+2) planes per dimension (mode="wrap"); afterwards all
-    kernel addressing is local and static.
-    """
-    n1, n2, n3 = field.shape
-    t1, t2, t3 = tile
-    assert n1 % t1 == 0 and n2 % t2 == 0 and n3 % t3 == 0, (field.shape, tile)
-    lo, hi = halo + 1, halo + 2
-    fpad = jnp.pad(field, ((lo, hi), (lo, hi), (lo, hi)), mode="wrap")
-    return tricubic_displace_pallas_padded(
-        fpad, disp, tile=tile, halo=halo, interpret=interpret
-    )
-
-
-# --------------------------------------------------------------------------- #
-# batched multi-field kernels: one DMA + one set of A-matrices serves C
-# channels.  The dim-1 contraction becomes (P, W1) @ (W1, C*W2*W3) on the
-# MXU — C x the arithmetic per A-matrix build, real intensity gains on this
-# memory-bound kernel — and the planned variants skip the per-point floor +
-# Lagrange-polynomial work entirely (precomputed InterpPlan operators).
-# --------------------------------------------------------------------------- #
-def _contract_channels(a1, a2, a3, fld, out_ref, s1, *, tile, channels):
-    """Shared epilogue: contract the 3 A-matrices against a (C,W1,W2,W3)
-    scratch block and store the slice result (C, 1, T2, T3)."""
-    t1, t2, t3 = tile
-    c = channels
-    w1, w2, w3 = fld.shape[1:]
-    p = t2 * t3
-    # MXU: (P, W1) x (C, W1, W2*W3) -> (P, C, W2*W3), contracting W1
-    s = jax.lax.dot_general(
-        a1,
-        fld.reshape(c, w1, w2 * w3),
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    s = s.reshape(p, c, w2, w3)
-    s = jnp.sum(a2[:, None, :, None] * s, axis=2)  # (P, C, W3)
-    res = jnp.sum(a3[:, None, :] * s, axis=2)  # (P, C)
-    out_ref[:, pl.ds(s1, 1), :, :] = res.T.reshape(c, 1, t2, t3).astype(out_ref.dtype)
-
-
-def _kernel_many(fpad_hbm, disp_ref, out_ref, scratch, sem, *, tile, halo, channels):
-    t1, t2, t3 = tile
-    w1 = t1 + 2 * halo + 3
-    w2 = t2 + 2 * halo + 3
-    w3 = t3 + 2 * halo + 3
-    i, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-
-    cp = pltpu.make_async_copy(
-        fpad_hbm.at[:, pl.ds(i * t1, w1), pl.ds(j * t2, w2), pl.ds(k * t3, w3)],
-        scratch,
-        sem,
-    )
-    cp.start()
-    cp.wait()
-    fld = scratch[...].astype(jnp.float32)  # (C, W1, W2, W3)
-
-    def one_slice(s1, _):
-        d1 = disp_ref[0, s1, :, :].astype(jnp.float32).reshape(-1)  # (P,)
-        d2 = disp_ref[1, s1, :, :].astype(jnp.float32).reshape(-1)
-        d3 = disp_ref[2, s1, :, :].astype(jnp.float32).reshape(-1)
-        p = d1.shape[0]
-        base2 = jax.lax.broadcasted_iota(jnp.float32, (t2, t3), 0).reshape(-1)
-        base3 = jax.lax.broadcasted_iota(jnp.float32, (t2, t3), 1).reshape(-1)
-        off = jnp.float32(halo + 1)
-        q1 = s1.astype(jnp.float32) + off + d1
-        q2 = base2 + off + d2
-        q3 = base3 + off + d3
-
-        def interp_matrix(q, w):
-            i0 = jnp.floor(q)
-            return _onehot_matrix(i0, lagrange_weights(q - i0), p, w)
-
-        _contract_channels(
-            interp_matrix(q1, w1), interp_matrix(q2, w2), interp_matrix(q3, w3),
-            fld, out_ref, s1, tile=tile, channels=channels,
-        )
-        return _
-
-    jax.lax.fori_loop(0, t1, one_slice, 0)
-
-
-def _kernel_planned(fpad_hbm, ib_ref, w_ref, out_ref, scratch, sem, *, tile, halo, channels):
+def _kernel_planned(fpad_hbm, ib_ref, w_ref, out_ref, scratch, planes, sem, *, tile, halo):
     """Planned variant: stencil bases + weights arrive precomputed (InterpPlan
-    blocks), so the per-point floor and weight polynomials are skipped — only
-    the one-hot scatter (tile-local by construction) remains per call."""
-    t1, t2, t3 = tile
-    w1 = t1 + 2 * halo + 3
-    w2 = t2 + 2 * halo + 3
-    w3 = t3 + 2 * halo + 3
-    i, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    blocks), so the per-point floor and weight polynomials are skipped."""
+    _stage_window(fpad_hbm, scratch, sem, tile=tile)
 
-    cp = pltpu.make_async_copy(
-        fpad_hbm.at[:, pl.ds(i * t1, w1), pl.ds(j * t2, w2), pl.ds(k * t3, w3)],
-        scratch,
-        sem,
-    )
-    cp.start()
-    cp.wait()
-    fld = scratch[...].astype(jnp.float32)
+    def one_slice(s1, carry):
+        rel = [ib_ref[d, s1, :, :] + halo for d in range(3)]
+        wts = [[w_ref[d, k, s1, :, :] for k in range(4)] for d in range(3)]
+        _contract_slice(scratch, planes, out_ref, s1, rel, wts, tile=tile, halo=halo)
+        return carry
 
-    def one_slice(s1, _):
-        ib1 = ib_ref[0, s1, :, :].astype(jnp.float32).reshape(-1)  # (P,)
-        ib2 = ib_ref[1, s1, :, :].astype(jnp.float32).reshape(-1)
-        ib3 = ib_ref[2, s1, :, :].astype(jnp.float32).reshape(-1)
-        p = ib1.shape[0]
-        base2 = jax.lax.broadcasted_iota(jnp.float32, (t2, t3), 0).reshape(-1)
-        base3 = jax.lax.broadcasted_iota(jnp.float32, (t2, t3), 1).reshape(-1)
-        off = jnp.float32(halo + 1)
-        # floor(x + d) = x + ib at integral home coordinates, so the local
-        # stencil base is directly home + ghost offset + ib
-        i0_1 = s1.astype(jnp.float32) + off + ib1
-        i0_2 = base2 + off + ib2
-        i0_3 = base3 + off + ib3
-        def wts(d):  # one (4, T2, T3) weight plane, sliced per x1-slice
-            return w_ref[d, :, s1, :, :].astype(jnp.float32).reshape(4, p)
-
-        a1 = _onehot_matrix(i0_1, wts(0), p, w1)
-        a2 = _onehot_matrix(i0_2, wts(1), p, w2)
-        a3 = _onehot_matrix(i0_3, wts(2), p, w3)
-        _contract_channels(a1, a2, a3, fld, out_ref, s1, tile=tile, channels=channels)
-        return _
-
-    jax.lax.fori_loop(0, t1, one_slice, 0)
+    jax.lax.fori_loop(0, tile[0], one_slice, 0)
 
 
-def _many_call(kern, fpad, operands, extra_in_specs, *, tile, halo, interpret):
-    """Shared pallas_call plumbing of the batched entries."""
+def _pallas(kern, fpad, operands, *, tile, halo, interpret):
+    """Shared pallas_call plumbing: ``fpad`` (C, ghost-extended) + per-point
+    operand blocks whose trailing three axes tile like the output."""
     pad = 2 * halo + 3
-    c = fpad.shape[0]
+    c, dtype = fpad.shape[0], fpad.dtype
     n1, n2, n3 = (s - pad for s in fpad.shape[1:])
     t1, t2, t3 = tile
     assert n1 % t1 == 0 and n2 % t2 == 0 and n3 % t3 == 0, ((n1, n2, n3), tile)
-    w = (c, t1 + 2 * halo + 3, t2 + 2 * halo + 3, t3 + 2 * halo + 3)
-    grid = (n1 // t1, n2 // t2, n3 // t3)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] + extra_in_specs,
-        out_specs=pl.BlockSpec((c, t1, t2, t3), lambda i, j, k: (0, i, j, k)),
-        out_shape=jax.ShapeDtypeStruct((c, n1, n2, n3), fpad.dtype),
-        scratch_shapes=[pltpu.VMEM(w, fpad.dtype), pltpu.SemaphoreType.DMA],
+    # the staged window is rounded up to whole (8, 128) tiles — DMA slices of
+    # tiled memory must be tile-aligned — so the hi side gets zero planes that
+    # keep the last window in bounds (never read by any stencil)
+    window = (t1 + pad, _round_up(t2 + pad, SUBLANES), _round_up(t3 + pad, LANES))
+    extra = [(0, 0), (0, 0)] + [(0, w - t - pad) for w, t in zip(window[1:], tile[1:])]
+    fpad = jnp.pad(fpad.astype(jnp.float32), extra)
+
+    def spec(x):
+        lead = x.ndim - 3
+        return pl.BlockSpec(x.shape[:lead] + tuple(tile),
+                            lambda i, j, k: (0,) * lead + (i, j, k))
+
+    out = pl.pallas_call(
+        functools.partial(kern, tile=tile, halo=halo),
+        grid=(n1 // t1, n2 // t2, n3 // t3),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.HBM)] + [spec(x) for x in operands],
+        out_specs=pl.BlockSpec((c,) + tuple(tile), lambda i, j, k: (0, i, j, k)),
+        out_shape=jax.ShapeDtypeStruct((c, n1, n2, n3), jnp.float32),
+        scratch_shapes=[
+            pltpu.VMEM((c,) + window, jnp.float32),
+            pltpu.VMEM((2, pad + 1, t2, t3), jnp.float32),
+            pltpu.SemaphoreType.DMA,
+        ],
         interpret=interpret,
     )(fpad, *operands)
+    return out.astype(dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "halo", "interpret"))
@@ -309,20 +190,19 @@ def tricubic_displace_pallas_padded_many(
     fpad: jnp.ndarray,
     disp: jnp.ndarray,
     *,
-    tile: tuple[int, int, int] = (8, 8, 32),
+    tile: tuple[int, int, int] = (8, 8, 128),
     halo: int = 4,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Batched kernel entry for an ALREADY ghost-extended stack.
 
-    ``fpad`` (C, N1+2H+3, N2+2H+3, N3+2H+3) — the layout produced by one
-    stacked ``jnp.pad(mode="wrap")`` or by the single batched ghost exchange
-    of ``repro.dist.halo``; ``disp`` (3, N1, N2, N3) shared by all channels.
+    ``fpad`` (C, N1+2H+3, N2+2H+3, N3+2H+3) has ``halo+1`` planes below and
+    ``halo+2`` above each axis — the layout produced by one stacked
+    ``jnp.pad(mode="wrap")`` or by the single batched ghost exchange of
+    ``repro.dist.halo``; ``disp`` (3, N1, N2, N3) is shared by all channels.
     """
-    t1, t2, t3 = tile
-    kern = functools.partial(_kernel_many, tile=tile, halo=halo, channels=fpad.shape[0])
-    spec = [pl.BlockSpec((3, t1, t2, t3), lambda i, j, k: (0, i, j, k))]
-    return _many_call(kern, fpad, (disp,), spec, tile=tile, halo=halo, interpret=interpret)
+    return _pallas(_kernel_displace, fpad, (disp.astype(jnp.float32),),
+                   tile=tile, halo=halo, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "halo", "interpret"))
@@ -331,22 +211,18 @@ def tricubic_apply_pallas_padded(
     ib: jnp.ndarray,
     w: jnp.ndarray,
     *,
-    tile: tuple[int, int, int] = (8, 8, 32),
+    tile: tuple[int, int, int] = (8, 8, 128),
     halo: int = 4,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Planned batched kernel entry: ``fpad`` (C, ghost-extended), plus the
     ``InterpPlan`` operator arrays ``ib`` (3, N..) / ``w`` (3, 4, N..)."""
-    t1, t2, t3 = tile
-    kern = functools.partial(_kernel_planned, tile=tile, halo=halo, channels=fpad.shape[0])
-    specs = [
-        pl.BlockSpec((3, t1, t2, t3), lambda i, j, k: (0, i, j, k)),
-        pl.BlockSpec((3, 4, t1, t2, t3), lambda i, j, k: (0, 0, i, j, k)),
-    ]
-    return _many_call(kern, fpad, (ib, w), specs, tile=tile, halo=halo, interpret=interpret)
+    return _pallas(_kernel_planned, fpad,
+                   (ib.astype(jnp.int32), w.astype(jnp.float32)),
+                   tile=tile, halo=halo, interpret=interpret)
 
 
-def _wrap_pad_many(fields: jnp.ndarray, halo: int) -> jnp.ndarray:
+def _wrap_pad(fields: jnp.ndarray, halo: int) -> jnp.ndarray:
     lo, hi = halo + 1, halo + 2
     return jnp.pad(fields, ((0, 0), (lo, hi), (lo, hi), (lo, hi)), mode="wrap")
 
@@ -355,26 +231,46 @@ def tricubic_displace_pallas_many(
     fields: jnp.ndarray,
     disp: jnp.ndarray,
     *,
-    tile: tuple[int, int, int] = (8, 8, 32),
+    tile: tuple[int, int, int] = (8, 8, 128),
     halo: int = 4,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Batched ``fields`` (C, N1,N2,N3) at x + disp, |disp| <= halo."""
+    """Batched ``fields`` (C, N1,N2,N3) at x + disp, |disp| <= halo.
+
+    Wrap-around periodicity is materialized once by pre-padding the stack
+    by (halo+1, halo+2) planes per axis (mode="wrap"); afterwards all
+    kernel addressing is local and static.
+    """
     return tricubic_displace_pallas_padded_many(
-        _wrap_pad_many(fields, halo), disp, tile=tile, halo=halo, interpret=interpret
+        _wrap_pad(fields, halo), disp, tile=tile, halo=halo, interpret=interpret
     )
+
+
+def tricubic_displace_pallas(
+    field: jnp.ndarray,
+    disp: jnp.ndarray,
+    *,
+    tile: tuple[int, int, int] = (8, 8, 128),
+    halo: int = 4,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Evaluate ``field`` (N1, N2, N3) at ``x + disp`` (grid units),
+    |disp| <= halo: the C=1 case of ``tricubic_displace_pallas_many``."""
+    return tricubic_displace_pallas_many(
+        field[None], disp, tile=tile, halo=halo, interpret=interpret
+    )[0]
 
 
 def tricubic_apply_pallas(
     fields: jnp.ndarray,
     plan: InterpPlan,
     *,
-    tile: tuple[int, int, int] = (8, 8, 32),
+    tile: tuple[int, int, int] = (8, 8, 128),
     halo: int = 4,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Planned batched apply (periodic wrap materialized by pre-padding)."""
     return tricubic_apply_pallas_padded(
-        _wrap_pad_many(fields, halo), plan.ib, plan.w,
+        _wrap_pad(fields, halo), plan.ib, plan.w,
         tile=tile, halo=halo, interpret=interpret,
     )

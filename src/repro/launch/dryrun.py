@@ -373,7 +373,7 @@ def lower_registration_cell(name: str, multi_pod: bool, verbose: bool = True, rc
     ctx = DistContext(grid, mesh, axes=axes, halo=rcfg.halo)
     cfg = gn.GNConfig(beta=rcfg.beta, n_t=rcfg.n_t, incompressible=rcfg.incompressible)
 
-    def reg_step(v, g0, rho_R, rho_T):
+    def reg_step(it, g0, rho_R, rho_T):
         prob = obj.Problem(
             grid=grid,
             rho_R=rho_R,
@@ -382,7 +382,7 @@ def lower_registration_cell(name: str, multi_pod: bool, verbose: bool = True, rc
             n_t=rcfg.n_t,
             incompressible=rcfg.incompressible,
         )
-        return gn.newton_iteration(v, g0, prob, ctx.ops, cfg, interp=ctx.interp)
+        return gn.newton_iteration(it, g0, prob, ctx.ops, cfg, interp=ctx.interp)
 
     vshape = jax.ShapeDtypeStruct(
         (3,) + grid.shape, jnp.float32, sharding=ctx.vector_sharding()
@@ -393,7 +393,8 @@ def lower_registration_cell(name: str, multi_pod: bool, verbose: bool = True, rc
     g0 = jax.ShapeDtypeStruct((), jnp.float32, sharding=NamedSharding(mesh, P()))
 
     t0 = time.time()
-    lowered = jax.jit(reg_step, donate_argnums=(0,)).lower(vshape, g0, sshape, sshape)
+    lowered = jax.jit(reg_step, donate_argnums=(0,)).lower(
+        gn.Iterate(vshape, vshape), g0, sshape, sshape)
     t_lower = time.time() - t0
     compiled = lowered.compile()
     t_compile = time.time() - t0 - t_lower
@@ -466,18 +467,19 @@ def lower_multilevel_cell(name: str, multi_pod: bool, verbose: bool = True, rcfg
         grid = fine_grid if shape == fine_grid.shape else make_grid(shape)
         ctx = fine_ctx if shape == fine_grid.shape else fine_ctx.coarsen(shape)
 
-        def reg_step(v, g0, rho_R, rho_T, _grid=grid, _ctx=ctx):
+        def reg_step(it, g0, rho_R, rho_T, _grid=grid, _ctx=ctx):
             prob = obj.Problem(
                 grid=_grid, rho_R=rho_R, rho_T=rho_T, beta=rcfg.beta,
                 n_t=rcfg.n_t, incompressible=rcfg.incompressible,
             )
-            return gn.newton_iteration(v, g0, prob, _ctx.ops, cfg, interp=_ctx.interp)
+            return gn.newton_iteration(it, g0, prob, _ctx.ops, cfg, interp=_ctx.interp)
 
         vshape = jax.ShapeDtypeStruct((3,) + grid.shape, jnp.float32, sharding=ctx.vector_sharding())
         sshape = jax.ShapeDtypeStruct(grid.shape, jnp.float32, sharding=ctx.scalar_sharding())
         g0 = jax.ShapeDtypeStruct((), jnp.float32, sharding=NamedSharding(mesh, P()))
         t0 = time.time()
-        compiled = jax.jit(reg_step, donate_argnums=(0,)).lower(vshape, g0, sshape, sshape).compile()
+        compiled = jax.jit(reg_step, donate_argnums=(0,)).lower(
+            gn.Iterate(vshape, vshape), g0, sshape, sshape).compile()
         t_newton = time.time() - t0
         level_rec = {
             "shape": list(shape),

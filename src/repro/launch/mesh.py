@@ -17,18 +17,27 @@ import to materialize placeholder devices.
 from __future__ import annotations
 
 import jax
-import numpy as np
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """The one place this repo builds a mesh: every axis is ``Auto``.
+
+    The pencil FFT, the halo exchange and the LM sharding rules are written
+    for GSPMD propagation (``Auto`` axes); ``jax.make_mesh`` defaults to
+    ``Explicit`` axes, under which their rolls, reshapes and duplicate specs
+    are refused.  ``devices`` defaults to ``jax.devices()``; pass described
+    devices (``jax.experimental.topologies``) to compile for absent chips.
+    """
+    shape, axes = tuple(shape), tuple(axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh for tests (e.g. (2,4)/("data","model") on 8 host devices)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)
 
 
 def mesh_axis_size(mesh, name: str) -> int:

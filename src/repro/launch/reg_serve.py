@@ -53,6 +53,7 @@ from repro import telemetry
 from repro.core import gauss_newton as gn
 from repro.core.grid import Grid, make_grid
 from repro.core.spectral import SpectralOps
+from repro.launch.compile_cache import enable_compile_cache
 from repro.resilience import health
 from repro.resilience import policy as res_policy
 
@@ -121,6 +122,9 @@ class CohortServer:
         S = slots
         self._jobs: list[RegJob | None] = [None] * S
         self._v = jnp.zeros((S, 3) + grid.shape, grid.dtype)
+        # Lap^2 of each slot's iterate, carried exactly (gn.with_lap2)
+        self._lap2 = jnp.zeros_like(self._v)
+        self._ops = ops
         self._rho_R = jnp.zeros((S,) + grid.shape, grid.dtype)
         self._rho_T = jnp.zeros((S,) + grid.shape, grid.dtype)
         self._g_forcing = np.full(S, _FORCING_SENTINEL, np.float32)
@@ -156,9 +160,14 @@ class CohortServer:
             if self._jobs[s] is None and self.queue:
                 job = self.queue.pop(0)
                 self._jobs[s] = job
-                self._v = self._v.at[s].set(
-                    0.0 if job.v0 is None else jnp.asarray(job.v0, self.grid.dtype)
-                )
+                if job.v0 is None:
+                    self._v = self._v.at[s].set(0.0)
+                    self._lap2 = self._lap2.at[s].set(0.0)
+                else:
+                    v0, lap2 = gn.with_lap2(jnp.asarray(job.v0, self.grid.dtype),
+                                            self._ops or SpectralOps(self.grid))
+                    self._v = self._v.at[s].set(v0)
+                    self._lap2 = self._lap2.at[s].set(lap2)
                 self._rho_R = self._rho_R.at[s].set(jnp.asarray(job.rho_R))
                 self._rho_T = self._rho_T.at[s].set(jnp.asarray(job.rho_T))
                 self._g_forcing[s] = _FORCING_SENTINEL
@@ -221,8 +230,8 @@ class CohortServer:
         active = self.active
         if not active.any():
             return []
-        self._v, log = self.step_fn(
-            self._v,
+        (self._v, self._lap2), log = self.step_fn(
+            gn.Iterate(self._v, self._lap2),
             jnp.asarray(self._g_forcing),
             jnp.asarray(active),
             jnp.float32(self.cfg.beta),
@@ -297,7 +306,7 @@ class CohortServer:
         if not telemetry.enabled():
             return
         lowered = self.step_fn.lower(
-            self._v,
+            gn.Iterate(self._v, self._lap2),
             jnp.asarray(self._g_forcing),
             jnp.asarray(self.active),
             jnp.float32(self.cfg.beta),
@@ -316,6 +325,7 @@ class CohortServer:
         zero_v = jnp.zeros((3,) + self.grid.shape, self.grid.dtype)
         tree = {
             "v": self._v,
+            "lap2": self._lap2,
             "rho_R": self._rho_R,
             "rho_T": self._rho_T,
             "queue_rho_R": [jnp.asarray(j.rho_R) for j in self.queue],
@@ -376,6 +386,10 @@ class CohortServer:
         srv = cls(grid, cfg, slots=len(meta["slot_jobs"]), ops=ops,
                   interp=interp, step_fn=step_fn)
         srv._v = jnp.asarray(tree["v"], grid.dtype)
+        if "lap2" in tree:
+            srv._lap2 = jnp.asarray(tree["lap2"], grid.dtype)
+        else:  # a snapshot from before the image was carried: transform once
+            srv._lap2 = gn.with_lap2(srv._v, ops or SpectralOps(grid)).lap2
         srv._rho_R = jnp.asarray(tree["rho_R"], grid.dtype)
         srv._rho_T = jnp.asarray(tree["rho_T"], grid.dtype)
         srv.iterations = int(meta["iterations"])
@@ -652,6 +666,7 @@ def main():
                     help="write a telemetry JSONL trace to this path "
                          "(render with: python -m repro.analysis.trace_report)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from repro.data.synthetic import synthetic_problem
 

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.mesh import make_mesh
 from repro.models.common import ShardRules
 from repro.train.steps import build_model, make_serve_step
 
@@ -32,7 +33,7 @@ def main():
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     rules = ShardRules(mesh)
     params, _ = model.init(jax.random.PRNGKey(args.seed), rules)
 

@@ -28,6 +28,8 @@ from repro.core import gauss_newton as gn
 from repro.core.registration import RegistrationConfig, register
 from repro.data import synthetic
 from repro.data.tokens import TokenStream
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models.common import ShardRules
 from repro.optim import adamw
 from repro.train.steps import build_model, make_train_step
@@ -77,7 +79,7 @@ def run_registration(args):
 def run_lm(args):
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     rules = ShardRules(mesh)
     opt_cfg = adamw.AdamWConfig(
         lr=args.lr, warmup_steps=min(100, args.steps // 10 + 1), total_steps=args.steps,
@@ -143,6 +145,7 @@ def main():
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--straggler-factor", type=float, default=3.0)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.mode == "registration":
         run_registration(args)
     else:

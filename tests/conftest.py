@@ -73,4 +73,6 @@ def jax_key(test_seed):
 
 @pytest.fixture(scope="session")
 def single_mesh():
-    return jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+
+    return make_mesh((1,), ("data",))

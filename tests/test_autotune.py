@@ -138,13 +138,13 @@ def test_counted_entries_never_apply_dtype_knobs(cache_path):
 
 def test_tuned_replace_explicit_value_wins(cache_path):
     tuned = TunedConfig(interp_method="pallas", field_dtype="bfloat16", mode="wall")
-    defaults = {"interp_method": "ref", "plan_dtype": None, "field_dtype": None}
+    defaults = {"interp_method": "auto", "plan_dtype": None, "field_dtype": None}
     cfg = tuned_replace(gn.GNConfig(), tuned, defaults)
     assert cfg.interp_method == "pallas" and cfg.field_dtype == "bfloat16"
     # user-pinned knobs survive
-    cfg = tuned_replace(gn.GNConfig(interp_method="auto", field_dtype="float32"),
+    cfg = tuned_replace(gn.GNConfig(interp_method="ref", field_dtype="float32"),
                         tuned, defaults)
-    assert cfg.interp_method == "auto" and cfg.field_dtype == "float32"
+    assert cfg.interp_method == "ref" and cfg.field_dtype == "float32"
 
 
 def test_consult_gn_cache_hit_skips_sweep(cache_path, monkeypatch):
@@ -225,13 +225,14 @@ def test_pcg_recursion_stays_f32_under_bf16_storage():
 
     v = jnp.zeros((3,) + grid.shape, jnp.float32)
     v_new, _ = gn.newton_iteration(
-        v, jnp.float32(1e-30), prob, ops, cfg, precond=recording_precond
+        gn.with_lap2(v, ops, cold=True), jnp.float32(1e-30), prob, ops, cfg,
+        precond=recording_precond,
     )
     assert seen, "preconditioner never invoked"
     assert all(d == jnp.float32 for d in seen), seen
     # the bf16 preconditioner output was upcast before seeding p0
     assert ops.precond_project(v + 1.0, 1e-2, False).dtype == jnp.bfloat16
-    assert v_new.dtype == jnp.float32
+    assert v_new.v.dtype == jnp.float32 and v_new.lap2.dtype == jnp.float32
 
 
 @pytest.mark.slow

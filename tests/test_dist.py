@@ -73,11 +73,11 @@ def test_distributed_gn_iteration_matches_local():
         cfg = gn.GNConfig()
         prob_l = obj.Problem(grid, rho_R, rho_T, 1e-2, 4, False)
         prob_d = obj.Problem(grid, ctx.shard_scalar(rho_R), ctx.shard_scalar(rho_T), 1e-2, 4, False)
-        v0 = jnp.zeros((3,)+grid.shape, jnp.float32)
+        v0 = gn.with_lap2(jnp.zeros((3,)+grid.shape, jnp.float32), local, cold=True)
         vl, ll = jax.jit(partial(gn.newton_iteration, prob=prob_l, ops=local, cfg=cfg))(v0, jnp.float32(1))
         vd, ld = jax.jit(partial(gn.newton_iteration, prob=prob_d, ops=ctx.ops, cfg=cfg, interp=ctx.interp))(
-            ctx.shard_vector(v0), jnp.float32(1))
-        assert float(jnp.max(jnp.abs(vl - vd))) < 1e-4
+            jax.tree.map(ctx.shard_vector, v0), jnp.float32(1))
+        assert float(jnp.max(jnp.abs(vl.v - vd.v))) < 1e-4
         assert int(ll.cg_iters) == int(ld.cg_iters)
         """
     )
@@ -100,11 +100,11 @@ def test_multipod_tuple_axis_pencil():
         cfg = gn.GNConfig()
         prob_d = obj.Problem(grid, ctx.shard_scalar(rho_R), ctx.shard_scalar(rho_T), 1e-2, 4, False)
         prob_l = obj.Problem(grid, rho_R, rho_T, 1e-2, 4, False)
-        v0 = jnp.zeros((3,)+grid.shape, jnp.float32)
+        v0 = gn.with_lap2(jnp.zeros((3,)+grid.shape, jnp.float32), local, cold=True)
         vd, _ = jax.jit(partial(gn.newton_iteration, prob=prob_d, ops=ctx.ops, cfg=cfg, interp=ctx.interp))(
-            ctx.shard_vector(v0), jnp.float32(1))
+            jax.tree.map(ctx.shard_vector, v0), jnp.float32(1))
         vl, _ = jax.jit(partial(gn.newton_iteration, prob=prob_l, ops=local, cfg=cfg))(v0, jnp.float32(1))
-        assert float(jnp.max(jnp.abs(vl - vd))) < 1e-4
+        assert float(jnp.max(jnp.abs(vl.v - vd.v))) < 1e-4
         """
     )
 
@@ -128,14 +128,14 @@ def test_distributed_incompressible_gn_matches_local():
         cfg = gn.GNConfig(incompressible=True)
         prob_l = obj.Problem(grid, rho_R, rho_T, 1e-2, 4, True)
         prob_d = obj.Problem(grid, ctx.shard_scalar(rho_R), ctx.shard_scalar(rho_T), 1e-2, 4, True)
-        v0 = jnp.zeros((3,)+grid.shape, jnp.float32)
+        v0 = gn.with_lap2(jnp.zeros((3,)+grid.shape, jnp.float32), local, cold=True)
         vl, ll = jax.jit(partial(gn.newton_iteration, prob=prob_l, ops=local, cfg=cfg))(v0, jnp.float32(1))
         vd, ld = jax.jit(partial(gn.newton_iteration, prob=prob_d, ops=ctx.ops, cfg=cfg, interp=ctx.interp))(
-            ctx.shard_vector(v0), jnp.float32(1))
-        assert float(jnp.max(jnp.abs(vl - vd))) < 1e-4
+            jax.tree.map(ctx.shard_vector, v0), jnp.float32(1))
+        assert float(jnp.max(jnp.abs(vl.v - vd.v))) < 1e-4
         assert int(ll.cg_iters) == int(ld.cg_iters)
         # the step stays (discretely) divergence free on the mesh
-        assert float(jnp.max(jnp.abs(ctx.ops.div(vd)))) < 1e-3
+        assert float(jnp.max(jnp.abs(ctx.ops.div(vd.v)))) < 1e-3
         """
     )
 
@@ -209,8 +209,8 @@ def test_mini_registration_dryrun_cells():
         import repro.launch.dryrun as dr
         from repro.launch import mesh as meshmod
         meshmod.make_production_mesh = lambda multi_pod=False: (
-            jax.make_mesh((2,2,2), ("pod","data","model")) if multi_pod
-            else jax.make_mesh((2,4), ("data","model")))
+            meshmod.make_mesh((2,2,2), ("pod","data","model")) if multi_pod
+            else meshmod.make_mesh((2,4), ("data","model")))
         dr.make_production_mesh = meshmod.make_production_mesh
         from repro.configs.claire_registration import RegConfig
         rcfg = RegConfig("mini-inc", (16, 16, 32), incompressible=True, halo=2)
@@ -276,8 +276,8 @@ def test_mini_dryrun_cell():
         from repro.launch import mesh as meshmod
         # shrink the production mesh for the 8-device subprocess
         meshmod.make_production_mesh = lambda multi_pod=False: (
-            jax.make_mesh((2,2,2), ("pod","data","model")) if multi_pod
-            else jax.make_mesh((2,4), ("data","model")))
+            meshmod.make_mesh((2,2,2), ("pod","data","model")) if multi_pod
+            else meshmod.make_mesh((2,4), ("data","model")))
         dr.make_production_mesh = meshmod.make_production_mesh
         import dataclasses
         from repro.configs import get_smoke_config
@@ -292,3 +292,16 @@ def test_mini_dryrun_cell():
         assert rec2["status"] == "ok", rec2
         """
     )
+
+
+def test_mesh_helper_builds_auto_axes():
+    """Every mesh comes from ``launch.mesh.make_mesh`` with ``Auto`` axes:
+    the pencil FFT's rolls and the halo path's reshapes are GSPMD code, and
+    ``jax.make_mesh``'s default ``Explicit`` axes refuse them."""
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1, 1), ("data", "model"))
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+    assert mesh.axis_names == ("data", "model")

@@ -139,7 +139,7 @@ def test_plan_bf16_through_solver_config(gn_setup):
     from repro.core import gauss_newton as gn
 
     g, ops, prob, v = gn_setup
-    interp = gn._interp_fn(gn.GNConfig(plan_dtype="bfloat16"))
+    interp = gn._interp_fn(gn.GNConfig(plan_dtype="bfloat16"), g)
     plan = make_plan(v, g, ops, 4, incompressible=False, interp=interp)
     assert plan.iplan_fwd.w.dtype == jnp.bfloat16
     assert plan.iplan_adj.w.dtype == jnp.bfloat16
@@ -266,3 +266,54 @@ def test_bench_interp_record_bf16_and_pallas_columns():
     for r in pallas_rows:
         assert r["pallas_mode"] in ("tpu", "interpret"), r
         assert r["pallas_rel_err"] < 1e-3, r
+
+
+# ----------------------------------------------------------------------- #
+# ghost budget of the local Pallas path
+# ----------------------------------------------------------------------- #
+def test_ghost_budget_is_a_cfl_bound():
+    # a unit-speed velocity moves N / (2 pi n_t) voxels per step: 5.1 at
+    # 128^3 and 10.2 at 256^3 with n_t 4, covered 1.5x
+    assert kops.ghost_budget((128,) * 3, 4) == 8
+    assert kops.ghost_budget((256,) * 3, 4) == 16
+    assert kops.ghost_budget((64, 64, 256), 4) == 16  # the finest axis rules
+    assert kops.ghost_budget((128,) * 3, 8) == 4  # more steps, shorter ones
+    assert kops.ghost_budget((16,) * 3, 4) == 4  # the floor
+
+
+def test_interp_widens_past_its_budget():
+    pallas = kops.make_interp(method="pallas", halo=8)
+    assert pallas.widened(8.0, (16, 16, 32)) is None  # within the budget
+    assert pallas.widened(float("nan"), (16, 16, 32)) is None  # not a halo fault
+    wider = pallas.widened(10.0, (16, 16, 32))  # a step of 10 voxels
+    assert wider.method == "pallas" and wider.halo == 15
+    with pytest.warns(UserWarning, match="widest ghost budget"):
+        gather = pallas.widened(12.0, (16, 16, 32))
+    assert gather.method == "ref"  # past MAX_HALO: the exact gather
+    # the gather has no budget to outgrow
+    assert kops.make_interp(method="ref", halo=8).widened(12.0, (16, 16, 32)) is None
+
+
+def test_local_pallas_solve_reruns_a_step_past_its_budget(monkeypatch):
+    """A local solve whose steps move past the Pallas ghost budget (here
+    shrunk to 1 voxel so a 16^3 problem reaches past it) reruns the step
+    with a widened budget: it follows the gather's path instead of meeting
+    NaNs."""
+    from repro import telemetry
+    from repro.core import gauss_newton as gn
+
+    rho_R, rho_T, _, grid = synthetic.synthetic_problem(16, n_t=1)
+    base = dict(n_t=1, autotune="off")
+    want = gn.solve(rho_R, rho_T, grid, gn.GNConfig(interp_method="ref", **base))
+    monkeypatch.setattr(kops, "ghost_budget", lambda shape, n_t: 1)
+    telemetry.reset_counters()
+    got = gn.solve(rho_R, rho_T, grid, gn.GNConfig(interp_method="pallas", **base))
+    counts = telemetry.counters()
+    # the step that overflowed was counted, then run again with a wider budget
+    assert counts.get("halo_budget_exceeded", 0) >= 1
+    assert counts.get("interp.halo_widened", 0) >= 1
+    assert np.isfinite(np.asarray(got["v"])).all()
+    assert got["status"] == want["status"], (got["status"], want["status"])
+    assert [h["cg_iters"] for h in got["history"]] == [h["cg_iters"] for h in want["history"]]
+    rel = np.linalg.norm(np.asarray(got["v"] - want["v"])) / np.linalg.norm(np.asarray(want["v"]))
+    assert rel < 1e-4, rel

@@ -239,7 +239,7 @@ def test_telemetry_does_not_change_mesh_collectives():
         ctx = DistContext(grid, mesh, halo=4)
         cfg = gn.GNConfig(n_t=2, max_cg=10)
         prob = obj.Problem(grid, rho_R, rho_T, 1e-2, 2, False)
-        vc = jnp.zeros((2, 3) + grid.shape, jnp.float32)
+        vc = gn.with_lap2(jnp.zeros((2, 3) + grid.shape, jnp.float32), ctx.ops, cold=True)
         gf = jnp.full((2,), 1e-30, jnp.float32)
         act = jnp.ones((2,), bool)
 
