@@ -11,6 +11,11 @@ The whole Newton iteration (plan + forward + adjoint + gradient + PCG +
 line search) is one jittable function — on the production mesh this gives
 XLA a single program per iteration to schedule collectives in, while the
 Python driver loop stays checkpointable.
+Its four phases trace under the named scopes ``gn.gradient`` (state,
+adjoint, gradient and its norm), ``gn.pcg`` (the preconditioned solve and
+the descent safeguard), ``gn.line_search`` (the Armijo trials) and
+``gn.update`` (acceptance, the carried ``Lap^2 v`` and the health guard),
+which split the step's device ops with no overlap.
 """
 from __future__ import annotations
 
@@ -334,8 +339,9 @@ def newton_iteration(
     v, lap2 = it
     interp = interp or _interp_fn(cfg, prob.grid)
     grid = prob.grid
-    state = obj.newton_state(v, prob, ops, interp, lap2=lap2)
-    gnorm = jnp.sqrt(grid.norm_sq(state.g))
+    with telemetry.annotate("gn.gradient"):
+        state = obj.newton_state(v, prob, ops, interp, lap2=lap2)
+        gnorm = jnp.sqrt(grid.norm_sq(state.g))
 
     # ---- Newton step: PCG on H dv = -g with (beta Lap^2)^{-1} preconditioner
     def matvec(p):
@@ -370,23 +376,24 @@ def newton_iteration(
         z = base_precond(r).astype(ct)
         return Iterate(z, image(r, z).astype(ct))
 
-    eta = jnp.minimum(cfg.eta_max, jnp.sqrt(gnorm / jnp.maximum(g0_forcing, 1e-30)))
-    rhs = -state.g
-    if prob.incompressible:
-        rhs = ops.leray(rhs)
-    rhs = rhs.astype(ct)
-    sol = pcg(matvec, rhs, wide_precond, grid.inner, eta, cfg.max_cg)
-    dv, ldv = sol.x
-    if prob.incompressible:
-        dv = ops.leray(dv).astype(ct)
+    with telemetry.annotate("gn.pcg"):
+        eta = jnp.minimum(cfg.eta_max, jnp.sqrt(gnorm / jnp.maximum(g0_forcing, 1e-30)))
+        rhs = -state.g
+        if prob.incompressible:
+            rhs = ops.leray(rhs)
+        rhs = rhs.astype(ct)
+        sol = pcg(matvec, rhs, wide_precond, grid.inner, eta, cfg.max_cg)
+        dv, ldv = sol.x
+        if prob.incompressible:
+            dv = ops.leray(dv).astype(ct)
+
+        gdv = grid.inner(state.g, dv)
+        # fall back to steepest descent if PCG returned a non-descent direction
+        dv = jnp.where(gdv < 0, dv, -spectral_precond(state.g).astype(ct))
+        ldv = jnp.where(gdv < 0, ldv, -_lap2_of_precond(state.g, prob.beta).astype(ct))
+        gdv = jnp.minimum(gdv, grid.inner(state.g, dv))
 
     # ---- Armijo backtracking on J
-    gdv = grid.inner(state.g, dv)
-    # fall back to steepest descent if PCG returned a non-descent direction
-    dv = jnp.where(gdv < 0, dv, -spectral_precond(state.g).astype(ct))
-    ldv = jnp.where(gdv < 0, ldv, -_lap2_of_precond(state.g, prob.beta).astype(ct))
-    gdv = jnp.minimum(gdv, grid.inner(state.g, dv))
-
     def j_of(vv):
         jval, (_, _, _, plan) = obj.evaluate_objective(vv, prob, ops, interp)
         return jval, _halo_need(plan, vv, grid)
@@ -401,38 +408,42 @@ def newton_iteration(
         alpha = alpha * 0.5
         return (alpha, j_of(v + alpha * dv)[0], it + 1)
 
-    alpha0 = jnp.float32(1.0)
-    j1, need1 = j_of(v + alpha0 * dv)
-    alpha, j_new, ls_it = jax.lax.while_loop(ls_cond, ls_body, (alpha0, j1, jnp.int32(0)))
-    accepted = j_new < state.j_val
-    v_new = jnp.where(accepted, v + alpha * dv, v)
+    with telemetry.annotate("gn.line_search"):
+        alpha0 = jnp.float32(1.0)
+        j1, need1 = j_of(v + alpha0 * dv)
+        alpha, j_new, ls_it = jax.lax.while_loop(ls_cond, ls_body, (alpha0, j1, jnp.int32(0)))
 
-    # in-graph health guard: classify the step (NaN/Inf, divergence, PCG
-    # breakdown) and revert a non-finite iterate to the last good one
-    status = health.classify(
-        v_in=v,
-        v_out=v_new,
-        j_val=state.j_val,
-        j_new=j_new,
-        gnorm=gnorm,
-        pcg_x=sol.x.v,
-        pcg_rel=sol.rel_res,
-        accepted=accepted,
-    )
-    lap2_new = jnp.where(accepted, lap2 + alpha * ldv, lap2)
-    it_new = Iterate(health.freeze(v_new, v, status), health.freeze(lap2_new, lap2, status))
+    with telemetry.annotate("gn.update"):
+        accepted = j_new < state.j_val
+        v_new = jnp.where(accepted, v + alpha * dv, v)
 
-    log = NewtonLog(
-        j_val=state.j_val,
-        misfit=state.misfit,
-        reg=state.reg,
-        gnorm=gnorm,
-        cg_iters=sol.iters,
-        step_len=jnp.where(accepted, alpha, 0.0),
-        ls_iters=ls_it,
-        status=status,
-        halo_need=jnp.maximum(_halo_need(state.plan, v, grid), need1),
-    )
+        # in-graph health guard: classify the step (NaN/Inf, divergence, PCG
+        # breakdown) and revert a non-finite iterate to the last good one
+        status = health.classify(
+            v_in=v,
+            v_out=v_new,
+            j_val=state.j_val,
+            j_new=j_new,
+            gnorm=gnorm,
+            pcg_x=sol.x.v,
+            pcg_rel=sol.rel_res,
+            accepted=accepted,
+        )
+        lap2_new = jnp.where(accepted, lap2 + alpha * ldv, lap2)
+        it_new = Iterate(health.freeze(v_new, v, status),
+                         health.freeze(lap2_new, lap2, status))
+
+        log = NewtonLog(
+            j_val=state.j_val,
+            misfit=state.misfit,
+            reg=state.reg,
+            gnorm=gnorm,
+            cg_iters=sol.iters,
+            step_len=jnp.where(accepted, alpha, 0.0),
+            ls_iters=ls_it,
+            status=status,
+            halo_need=jnp.maximum(_halo_need(state.plan, v, grid), need1),
+        )
     return it_new, log
 
 
@@ -651,8 +662,9 @@ def newton_iteration_cohort(
     v, lap2 = it
     interp = interp or _interp_fn(cfg, prob.grid)
     grid = prob.grid
-    state = obj.newton_state(v, prob, ops, interp, lap2=lap2)
-    gnorm = jnp.sqrt(grid.norm_sq_per(state.g))
+    with telemetry.annotate("gn.gradient"):
+        state = obj.newton_state(v, prob, ops, interp, lap2=lap2)
+        gnorm = jnp.sqrt(grid.norm_sq_per(state.g))
 
     def bc(s):  # (S,) -> (S,1,1,1,1)
         return s.reshape(s.shape + (1,) * (v.ndim - 1))
@@ -670,21 +682,22 @@ def newton_iteration_cohort(
         z = spectral_precond(r).astype(ct)
         return Iterate(z, _lap2_of_precond(r, prob.beta).astype(ct))
 
-    eta = jnp.minimum(cfg.eta_max, jnp.sqrt(gnorm / jnp.maximum(g0_forcing, 1e-30)))
-    rhs = -state.g
-    if prob.incompressible:
-        rhs = ops.leray(rhs)
-    rhs = rhs.astype(ct)
-    sol = pcg_masked(matvec, rhs, wide_precond, grid.inner_per, eta, cfg.max_cg, active)
-    dv, ldv = sol.x
-    if prob.incompressible:
-        dv = ops.leray(dv).astype(ct)
+    with telemetry.annotate("gn.pcg"):
+        eta = jnp.minimum(cfg.eta_max, jnp.sqrt(gnorm / jnp.maximum(g0_forcing, 1e-30)))
+        rhs = -state.g
+        if prob.incompressible:
+            rhs = ops.leray(rhs)
+        rhs = rhs.astype(ct)
+        sol = pcg_masked(matvec, rhs, wide_precond, grid.inner_per, eta, cfg.max_cg, active)
+        dv, ldv = sol.x
+        if prob.incompressible:
+            dv = ops.leray(dv).astype(ct)
 
-    # per-subject steepest-descent safeguard
-    gdv = grid.inner_per(state.g, dv)
-    dv = jnp.where(bc(gdv < 0), dv, -spectral_precond(state.g).astype(ct))
-    ldv = jnp.where(bc(gdv < 0), ldv, -_lap2_of_precond(state.g, prob.beta).astype(ct))
-    gdv = jnp.minimum(gdv, grid.inner_per(state.g, dv))
+        # per-subject steepest-descent safeguard
+        gdv = grid.inner_per(state.g, dv)
+        dv = jnp.where(bc(gdv < 0), dv, -spectral_precond(state.g).astype(ct))
+        ldv = jnp.where(bc(gdv < 0), ldv, -_lap2_of_precond(state.g, prob.beta).astype(ct))
+        gdv = jnp.minimum(gdv, grid.inner_per(state.g, dv))
 
     def j_of(vv):
         jval, _ = obj.evaluate_objective(vv, prob, ops, interp)
@@ -707,40 +720,44 @@ def newton_iteration_cohort(
         jnew = jnp.where(halve, jtrial, jnew)
         return (alpha, jnew, it + 1)
 
-    alpha0 = jnp.ones((v.shape[0],), jnp.float32)
-    j1 = j_of(v + bc(alpha0) * dv)
-    alpha, j_new, ls_it = jax.lax.while_loop(ls_cond, ls_body, (alpha0, j1, jnp.int32(0)))
-    accepted = active & (j_new < state.j_val)
-    v_new = jnp.where(bc(accepted), v + bc(alpha) * dv, v)
-    lap2_new = jnp.where(bc(accepted), lap2 + bc(alpha) * ldv, lap2)
+    with telemetry.annotate("gn.line_search"):
+        alpha0 = jnp.ones((v.shape[0],), jnp.float32)
+        j1 = j_of(v + bc(alpha0) * dv)
+        alpha, j_new, ls_it = jax.lax.while_loop(ls_cond, ls_body, (alpha0, j1, jnp.int32(0)))
 
-    # per-subject in-graph health guard: the reductions keep the subjects
-    # axis, and a sick subject's iterate is frozen so its NaNs never feed
-    # the cohort's shared transform rides on the next step
-    status = health.classify(
-        v_in=v,
-        v_out=v_new,
-        j_val=state.j_val,
-        j_new=j_new,
-        gnorm=gnorm,
-        pcg_x=sol.x.v,
-        pcg_rel=sol.rel_res,
-        accepted=accepted,
-        active=active,
-        axes=tuple(range(1, v.ndim)),
-    )
-    it_new = Iterate(health.freeze(v_new, v, status), health.freeze(lap2_new, lap2, status))
+    with telemetry.annotate("gn.update"):
+        accepted = active & (j_new < state.j_val)
+        v_new = jnp.where(bc(accepted), v + bc(alpha) * dv, v)
+        lap2_new = jnp.where(bc(accepted), lap2 + bc(alpha) * ldv, lap2)
 
-    log = NewtonLog(
-        j_val=state.j_val,
-        misfit=state.misfit,
-        reg=state.reg,
-        gnorm=gnorm,
-        cg_iters=sol.iters,
-        step_len=jnp.where(accepted, alpha, 0.0),
-        ls_iters=ls_it,  # shared lockstep halvings (scalar, not per-subject)
-        status=status,
-    )
+        # per-subject in-graph health guard: the reductions keep the subjects
+        # axis, and a sick subject's iterate is frozen so its NaNs never feed
+        # the cohort's shared transform rides on the next step
+        status = health.classify(
+            v_in=v,
+            v_out=v_new,
+            j_val=state.j_val,
+            j_new=j_new,
+            gnorm=gnorm,
+            pcg_x=sol.x.v,
+            pcg_rel=sol.rel_res,
+            accepted=accepted,
+            active=active,
+            axes=tuple(range(1, v.ndim)),
+        )
+        it_new = Iterate(health.freeze(v_new, v, status),
+                         health.freeze(lap2_new, lap2, status))
+
+        log = NewtonLog(
+            j_val=state.j_val,
+            misfit=state.misfit,
+            reg=state.reg,
+            gnorm=gnorm,
+            cg_iters=sol.iters,
+            step_len=jnp.where(accepted, alpha, 0.0),
+            ls_iters=ls_it,  # shared lockstep halvings (scalar, not per-subject)
+            status=status,
+        )
     return it_new, log
 
 

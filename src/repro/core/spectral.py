@@ -41,6 +41,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.core.grid import Grid
 
 
@@ -333,10 +334,13 @@ class SpectralOps:
         """Open a transform-coalescing batch (see ``SpectralBatch``)."""
         return SpectralBatch(self)
 
+    @telemetry.annotate("fft.inv")
     def inv_real(self, spec: jnp.ndarray) -> jnp.ndarray:
         """Inverse transform of real-destined spectra; uses the backend's
         complex-packed inverse (PencilFFT(packed=True)) when available —
-        halves inverse-side all-to-all bytes (EXPERIMENTS §Perf)."""
+        halves inverse-side all-to-all bytes (EXPERIMENTS §Perf).  Every
+        inverse of the toolbox passes here, under the named scope
+        ``fft.inv``."""
         if getattr(self.fft, "packed", False) and spec.ndim > 3:
             lead = spec.shape[:-3]
             flat = spec.reshape((-1,) + spec.shape[-3:])
@@ -348,11 +352,14 @@ class SpectralOps:
             out = out.astype(self.field_dtype)
         return out
 
+    @telemetry.annotate("fft.fwd")
     def fwd_real(self, u: jnp.ndarray) -> jnp.ndarray:
         """Forward transform of REAL fields; pairs of a batched stack ride
         the backend's packed forward (``PencilFFT.fwd_packed``) when
         available — the forward-side mirror of ``inv_real``, halving the
-        forward all-to-all bytes of gradient/Leray/coalesced-batch stacks."""
+        forward all-to-all bytes of gradient/Leray/coalesced-batch stacks.
+        Every forward of the toolbox passes here, under the named scope
+        ``fft.fwd``."""
         if getattr(self.fft, "packed", False) and u.ndim > 3:
             lead = u.shape[:-3]
             flat = u.reshape((-1,) + u.shape[-3:])

@@ -189,7 +189,9 @@ def make_halo_interp(grid: Grid, mesh, axes=("data", "model"), halo: int = 4,
     runs the same single ghost-exchange sequence per call.  ``plan_dtype``
     packs the cached plan weights (``jnp.bfloat16`` halves the plan's HBM
     footprint per shard; the per-shard contraction still upcasts to f32 —
-    see ``ref.make_interp_plan``).
+    see ``ref.make_interp_plan``).  The three entries trace under the
+    named scopes of ``kernels.ops.Interp``: ``interp.displace``,
+    ``interp.plan`` and ``interp.apply``.
     """
     a1, a2 = tuple(axes)
     p1, p2 = mesh_axes_size(mesh, a1), mesh_axes_size(mesh, a2)
@@ -215,6 +217,7 @@ def make_halo_interp(grid: Grid, mesh, axes=("data", "model"), halo: int = 4,
     sm_apply5 = shard_map(partial(_apply_local_cohort, **kw), in_specs=(s_coh, s_coh, s_coh_w),
                           out_specs=s_coh, **smkw)
 
+    @telemetry.annotate("interp.displace")
     def interp(field, disp):
         if disp.ndim == 5:  # cohort: per-subject displacements
             lead = field.shape[:-4]
@@ -224,6 +227,7 @@ def make_halo_interp(grid: Grid, mesh, axes=("data", "model"), halo: int = 4,
         out = sm4(field.reshape((-1,) + field.shape[-3:]), disp)
         return out.reshape(lead + out.shape[-3:])
 
+    @telemetry.annotate("interp.apply")
     def apply_plan(fields, plan: ref.InterpPlan):
         if plan.ib.ndim == 5:  # cohort plan: per-subject operators
             lead = fields.shape[:-4]
@@ -233,8 +237,12 @@ def make_halo_interp(grid: Grid, mesh, axes=("data", "model"), halo: int = 4,
         out = sm_apply(fields.reshape((-1,) + fields.shape[-3:]), plan.ib, plan.w)
         return out.reshape(lead + out.shape[-3:])
 
+    @telemetry.annotate("interp.plan")
+    def make_plan(disp):
+        return ref.make_interp_plan(disp, dtype=plan_dtype)
+
     interp.kernel = kernel
-    interp.make_plan = partial(ref.make_interp_plan, dtype=plan_dtype)
+    interp.make_plan = make_plan
     interp.apply_plan = apply_plan
     return interp
 
@@ -285,6 +293,7 @@ def make_checked_interp(halo_interp, mesh, axes, halo: int, on_overflow: str = "
             need,
         )
 
+    @telemetry.annotate("interp.displace")
     def checked(field, disp):
         need = jnp.ceil(kops.max_displacement(disp))
         if on_overflow != "gather":
@@ -301,6 +310,7 @@ def make_checked_interp(halo_interp, mesh, axes, halo: int, on_overflow: str = "
             disp,
         )
 
+    @telemetry.annotate("interp.apply")
     def checked_apply(fields, plan: ref.InterpPlan):
         if on_overflow != "gather":
             return kops.poison_beyond_halo(halo_interp.apply_plan(fields, plan),

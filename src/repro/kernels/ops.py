@@ -182,6 +182,11 @@ def tricubic_displace_many(
 class Interp:
     """Plan-aware single-device interpolation executor (see module docstring).
 
+    Its three entries trace under the named scopes ``interp.displace``,
+    ``interp.plan`` and ``interp.apply`` (``telemetry.annotate``): a
+    profile finds the interpolation's device ops by those names, whatever
+    kernel implements them.
+
     ``method``/``halo``/``tile`` follow ``tricubic_displace``: on the Pallas
     path a displacement beyond ``halo`` NaN-poisons the result
     (``poison_beyond_halo``, read off the plan's cached ``halo_need``).
@@ -197,6 +202,7 @@ class Interp:
         self.tile = tile
         self.plan_dtype = plan_dtype
 
+    @telemetry.annotate("interp.displace")
     def __call__(self, field: jnp.ndarray, disp: jnp.ndarray) -> jnp.ndarray:
         if field.ndim == 3:
             return tricubic_displace(
@@ -206,9 +212,11 @@ class Interp:
             field, disp, method=self.method, halo=self.halo, tile=self.tile
         )
 
+    @telemetry.annotate("interp.plan")
     def make_plan(self, disp: jnp.ndarray) -> ref.InterpPlan:
         return ref.make_interp_plan(disp, dtype=self.plan_dtype)
 
+    @telemetry.annotate("interp.apply")
     def apply_plan(self, fields: jnp.ndarray, plan: ref.InterpPlan) -> jnp.ndarray:
         shape3 = fields.shape[-3:]
         rs = resolve(self.method, shape3, self.tile)

@@ -97,6 +97,11 @@ class JobResult:
     # "diverged" | "pcg_breakdown" (``repro.resilience.health`` names)
     status: str = ""
     attempts: int = 1  # serve attempt that produced this result
+    # the carried ``Lap^2 v`` of the retired slot (``gn.Iterate``), left on
+    # the device, on the result ``CohortServer.step`` returns; None in
+    # ``CohortServer.results``, in ``serve_jobs``' results and where the
+    # result was rebuilt from a checkpoint
+    lap2: Any = None
 
 
 class CohortServer:
@@ -156,31 +161,32 @@ class CohortServer:
         return np.asarray([j is not None for j in self._jobs])
 
     def _fill_slots(self) -> None:
-        for s in range(self.slots):
-            if self._jobs[s] is None and self.queue:
-                job = self.queue.pop(0)
-                self._jobs[s] = job
-                if job.v0 is None:
-                    self._v = self._v.at[s].set(0.0)
-                    self._lap2 = self._lap2.at[s].set(0.0)
-                else:
-                    v0, lap2 = gn.with_lap2(jnp.asarray(job.v0, self.grid.dtype),
-                                            self._ops or SpectralOps(self.grid))
-                    self._v = self._v.at[s].set(v0)
-                    self._lap2 = self._lap2.at[s].set(lap2)
-                self._rho_R = self._rho_R.at[s].set(jnp.asarray(job.rho_R))
-                self._rho_T = self._rho_T.at[s].set(jnp.asarray(job.rho_T))
-                self._g_forcing[s] = _FORCING_SENTINEL
-                self._g0_preset[s] = job.g0_ref is not None
-                self._g0[s] = job.g0_ref if job.g0_ref is not None else 0.0
-                self._newton[s] = 0
-                self._cg[s] = 0
-                if self.iterations > 0:
-                    self.refills += 1
-                self._admitted_at[s] = self.iterations
-                self._queue_wait[s] = self.iterations - self._enqueued_at.pop(
-                    id(job), self.iterations
-                )
+        with telemetry.span("serve.fill"):
+            for s in range(self.slots):
+                if self._jobs[s] is None and self.queue:
+                    job = self.queue.pop(0)
+                    self._jobs[s] = job
+                    if job.v0 is None:
+                        self._v = self._v.at[s].set(0.0)
+                        self._lap2 = self._lap2.at[s].set(0.0)
+                    else:
+                        v0, lap2 = gn.with_lap2(jnp.asarray(job.v0, self.grid.dtype),
+                                                self._ops or SpectralOps(self.grid))
+                        self._v = self._v.at[s].set(v0)
+                        self._lap2 = self._lap2.at[s].set(lap2)
+                    self._rho_R = self._rho_R.at[s].set(jnp.asarray(job.rho_R))
+                    self._rho_T = self._rho_T.at[s].set(jnp.asarray(job.rho_T))
+                    self._g_forcing[s] = _FORCING_SENTINEL
+                    self._g0_preset[s] = job.g0_ref is not None
+                    self._g0[s] = job.g0_ref if job.g0_ref is not None else 0.0
+                    self._newton[s] = 0
+                    self._cg[s] = 0
+                    if self.iterations > 0:
+                        self.refills += 1
+                    self._admitted_at[s] = self.iterations
+                    self._queue_wait[s] = self.iterations - self._enqueued_at.pop(
+                        id(job), self.iterations
+                    )
 
     def _retire(self, s: int, converged: bool, status: str) -> JobResult:
         job = self._jobs[s]
@@ -194,9 +200,12 @@ class CohortServer:
             converged=converged,
             status=status,
             attempts=int(job.attempt),
+            lap2=self._lap2[s],
         )
         self._jobs[s] = None
-        self.results.append(res)
+        # only the result ``step`` returns holds ``lap2``: the server's record
+        # keeps none, so device memory does not grow with the jobs retired
+        self.results.append(dataclasses.replace(res, lap2=None))
         if status in health.FAILED_NAMES:
             telemetry.counter(
                 "resilience.guard_tripped", status=status, source="reg_serve"
@@ -223,65 +232,80 @@ class CohortServer:
         return res
 
     def step(self) -> list[JobResult]:
-        """Fill free slots, advance one masked Newton iteration, retire."""
+        """Fill free slots, advance one masked Newton iteration, retire.
+
+        A profiler trace shows the step as the host span ``serve.step``
+        with the children ``serve.fill`` (slot writes), ``serve.dispatch``
+        (the compiled step's call and its argument transfers),
+        ``serve.read_log`` (the pulls of the step's log, which wait for the
+        device) and ``serve.retire`` (host bookkeeping and the retired
+        velocities' pulls)."""
+        with telemetry.span("serve.step"):
+            return self._step()
+
+    def _step(self) -> list[JobResult]:
         for hook in list(self.hooks):
             hook(self)
         self._fill_slots()
         active = self.active
         if not active.any():
             return []
-        (self._v, self._lap2), log = self.step_fn(
-            gn.Iterate(self._v, self._lap2),
-            jnp.asarray(self._g_forcing),
-            jnp.asarray(active),
-            jnp.float32(self.cfg.beta),
-            self._rho_R,
-            self._rho_T,
-        )
-        self.iterations += 1
-        gnorm = np.asarray(log.gnorm, np.float32)
-        step_len = np.asarray(log.step_len)
-        code = np.asarray(log.status, np.int64)
-        self._newton += active
-        self._cg += np.asarray(log.cg_iters, np.int64)
-        retired = []
-        for s in range(self.slots):
-            if not active[s]:
-                continue
-            # a freshly admitted subject's first iterate fixes its
-            # Eisenstat-Walker forcing reference, and — unless the job
-            # supplied an explicit g0_ref (warm-started blocks do) — its
-            # termination reference (the decoupling of gn.solve, per slot)
-            if self._g_forcing[s] == _FORCING_SENTINEL:
-                self._g_forcing[s] = gnorm[s]
-                if not self._g0_preset[s]:
-                    self._g0[s] = gnorm[s]
-            self._rel[s] = gnorm[s] / max(self._g0[s], _FORCING_SENTINEL)
-            converged = bool(self._rel[s] <= self.cfg.gtol)
-            # retirement reason: the in-graph guard decides the failure
-            # modes; the host decides converged / stagnated / max_newton
-            if int(code[s]) in health.FAILED_CODES:
-                status = health.status_name(int(code[s]))
-                converged = False
-            elif converged:
-                status = health.status_name(health.CONVERGED)
-            elif step_len[s] == 0.0:
-                status = health.status_name(health.STAGNATED)
-            elif self._newton[s] >= self.cfg.max_newton:
-                status = health.status_name(health.MAX_NEWTON)
-            else:
-                continue
-            retired.append(self._retire(s, converged, status))
-        telemetry.emit(
-            telemetry.ServeStepEvent(
-                iteration=self.iterations,
-                slots=self.slots,
-                occupancy=int(active.sum()),
-                queue_len=len(self.queue),
-                refills=self.refills,
+        with telemetry.span("serve.dispatch"):
+            (self._v, self._lap2), log = self.step_fn(
+                gn.Iterate(self._v, self._lap2),
+                jnp.asarray(self._g_forcing),
+                jnp.asarray(active),
+                jnp.float32(self.cfg.beta),
+                self._rho_R,
+                self._rho_T,
             )
-        )
-        return retired
+        self.iterations += 1
+        with telemetry.span("serve.read_log"):
+            gnorm = np.asarray(log.gnorm, np.float32)
+            step_len = np.asarray(log.step_len)
+            code = np.asarray(log.status, np.int64)
+            cg_iters = np.asarray(log.cg_iters, np.int64)
+        with telemetry.span("serve.retire"):
+            self._newton += active
+            self._cg += cg_iters
+            retired = []
+            for s in range(self.slots):
+                if not active[s]:
+                    continue
+                # a freshly admitted subject's first iterate fixes its
+                # Eisenstat-Walker forcing reference, and — unless the job
+                # supplied an explicit g0_ref (warm-started blocks do) — its
+                # termination reference (the decoupling of gn.solve, per slot)
+                if self._g_forcing[s] == _FORCING_SENTINEL:
+                    self._g_forcing[s] = gnorm[s]
+                    if not self._g0_preset[s]:
+                        self._g0[s] = gnorm[s]
+                self._rel[s] = gnorm[s] / max(self._g0[s], _FORCING_SENTINEL)
+                converged = bool(self._rel[s] <= self.cfg.gtol)
+                # retirement reason: the in-graph guard decides the failure
+                # modes; the host decides converged / stagnated / max_newton
+                if int(code[s]) in health.FAILED_CODES:
+                    status = health.status_name(int(code[s]))
+                    converged = False
+                elif converged:
+                    status = health.status_name(health.CONVERGED)
+                elif step_len[s] == 0.0:
+                    status = health.status_name(health.STAGNATED)
+                elif self._newton[s] >= self.cfg.max_newton:
+                    status = health.status_name(health.MAX_NEWTON)
+                else:
+                    continue
+                retired.append(self._retire(s, converged, status))
+            telemetry.emit(
+                telemetry.ServeStepEvent(
+                    iteration=self.iterations,
+                    slots=self.slots,
+                    occupancy=int(active.sum()),
+                    queue_len=len(self.queue),
+                    refills=self.refills,
+                )
+            )
+            return retired
 
     def run(self, verbose: bool = False) -> list[JobResult]:
         self._echo = verbose
@@ -294,26 +318,6 @@ class CohortServer:
 
     def compiled_executables(self) -> int:
         return int(self.step_fn._cache_size())
-
-    def emit_step_collectives(self, label: str = "cohort_step") -> None:
-        """Emit per-kind collective counts for this bucket's step executable.
-
-        Ahead-of-time lowering: does not populate the jit cache, so the
-        one-executable pin of ``compiled_executables`` is unaffected.  No-op
-        unless a telemetry sink is installed (lowering+compiling a second
-        copy of the step is not free).
-        """
-        if not telemetry.enabled():
-            return
-        lowered = self.step_fn.lower(
-            gn.Iterate(self._v, self._lap2),
-            jnp.asarray(self._g_forcing),
-            jnp.asarray(self.active),
-            jnp.float32(self.cfg.beta),
-            self._rho_R,
-            self._rho_T,
-        )
-        telemetry.emit_collectives(label, lowered)
 
     # ------------------------------------------------------------------ #
     # checkpointed job streams: the snapshot is standalone — it carries the
@@ -597,7 +601,7 @@ def serve_jobs(jobs: list[RegJob], cfg: gn.GNConfig, slots: int = 4,
             return
         if res.status in health.FAILED_NAMES:
             telemetry.counter("resilience.jobs_failed", status=res.status)
-        final.append(res)
+        final.append(dataclasses.replace(res, lap2=None))
 
     def _save_session() -> None:
         tree: dict = {"buckets": {}, "results_v": [jnp.asarray(r.v) for r in final]}
@@ -635,8 +639,6 @@ def serve_jobs(jobs: list[RegJob], cfg: gn.GNConfig, slots: int = 4,
     execs: dict[int, int] = {}
     for (shape, attempt), srv in servers.items():
         key = shape if attempt == 1 else shape + (f"retry{attempt}",)
-        if attempt == 1:
-            srv.emit_step_collectives(f"cohort_step{shape}")
         stats[key] = {
             "jobs": srv.admitted,
             "attempt": attempt,

@@ -44,6 +44,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax import lax
 
+from repro import telemetry
 from repro.core.spectral import LocalFFT, SpectralOps, nyquist_mask
 
 
@@ -147,12 +148,26 @@ def pad_spec(
     return spec
 
 
+@telemetry.annotate("fft.fwd")
+def _fwd(ops: SpectralOps, f: jnp.ndarray) -> jnp.ndarray:
+    """``ops``' forward transform, under the scope ``SpectralOps``' own
+    transforms carry."""
+    return ops.fft.fwd(f)
+
+
+@telemetry.annotate("fft.inv")
+def _inv(ops: SpectralOps, spec: jnp.ndarray) -> jnp.ndarray:
+    """``ops``' inverse transform, under the scope ``SpectralOps``' own
+    transforms carry."""
+    return ops.fft.inv(spec)
+
+
 def restrict(f: jnp.ndarray, fine_ops: SpectralOps, coarse_ops: SpectralOps) -> jnp.ndarray:
     """Sample ``f``'s band-limited interpolant on the coarse grid.
 
     ``f``: (..., N1, N2, N3) on ``fine_ops.grid``; returns (..., M1, M2, M3).
     """
-    return coarse_ops.fft.inv(restrict_spec(fine_ops.fft.fwd(f), fine_ops, coarse_ops))
+    return _inv(coarse_ops, restrict_spec(_fwd(fine_ops, f), fine_ops, coarse_ops))
 
 
 def prolong(g: jnp.ndarray, coarse_ops: SpectralOps, fine_ops: SpectralOps) -> jnp.ndarray:
@@ -160,7 +175,7 @@ def prolong(g: jnp.ndarray, coarse_ops: SpectralOps, fine_ops: SpectralOps) -> j
 
     ``g``: (..., M1, M2, M3) on ``coarse_ops.grid``; returns (..., N1, N2, N3).
     """
-    return fine_ops.fft.inv(pad_spec(coarse_ops.fft.fwd(g), coarse_ops, fine_ops))
+    return _inv(fine_ops, pad_spec(_fwd(coarse_ops, g), coarse_ops, fine_ops))
 
 
 def smooth_restrict(
@@ -175,5 +190,5 @@ def smooth_restrict(
     multiplier rides the restriction's own forward transform.
     """
     _check_pair(fine_ops, coarse_ops)
-    spec = fine_ops.fft.fwd(f) * fine_ops._smooth_scale(coarse_ops.grid.spacing)
-    return coarse_ops.fft.inv(restrict_spec(spec, fine_ops, coarse_ops))
+    spec = _fwd(fine_ops, f) * fine_ops._smooth_scale(coarse_ops.grid.spacing)
+    return _inv(coarse_ops, restrict_spec(spec, fine_ops, coarse_ops))

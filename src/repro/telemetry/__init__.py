@@ -2,10 +2,14 @@
 
 Structured tracing, counters, and per-job metrics for every layer:
 
-* ``span(name)`` — nestable wall-clock timer (``block_until_ready`` at
-  exit via ``sp.sync(x)``); near-zero overhead and zero trace-graph impact
-  when no sink is installed; optional ``jax.profiler.TraceAnnotation``
-  bridge (``configure(profiler=True)``).
+* ``span(name)`` — nestable host phase: always a
+  ``jax.profiler.TraceAnnotation`` of its name (so a profiler trace shows
+  it beside the device ops); with a sink installed also a wall-clock timer
+  (``block_until_ready`` at exit via ``sp.sync(x)``) that emits a
+  SpanEvent.  Zero trace-graph impact either way.
+* ``annotate(name)`` — the device side: puts the phase's name in the HLO
+  metadata of the ops it traces (the ``op_name`` stack, and a frontend
+  attribute that a TPU trace prints in each op's name).
 * typed events (``events.py``) with a versioned JSON-lines schema —
   Newton iterations, ladder levels, serve jobs, counters, collectives,
   bench rows — validated by ``validate_record`` (the CI contract).
@@ -43,7 +47,6 @@ from repro.telemetry.events import (
 from repro.telemetry.runtime import (
     add_sink,
     annotate,
-    configure,
     configure_from_env,
     console_sink,
     counter,
@@ -84,7 +87,6 @@ __all__ = [
     "sinks",
     "add_sink",
     "remove_sink",
-    "configure",
     "configure_from_env",
     "jsonl_sink",
     "console_sink",

@@ -3,25 +3,30 @@
 Design constraints (the ISSUE 7 tentpole):
 
 * **near-zero overhead, zero trace-graph impact when disabled** — with no
-  sink installed, ``span.__enter__``/``__exit__`` are two attribute checks
-  and ``emit`` is one; nothing here ever inserts an op into a traced
+  sink installed, a span opens a profiler annotation and makes one attribute
+  check (``emit`` makes one); nothing here ever inserts an op into a traced
   program (spans live in the Python driver loops, ``annotate`` is pure
-  HLO-metadata ``jax.named_scope``), so enabling/disabling telemetry can
+  HLO metadata), so enabling/disabling telemetry can
   not change compiled executables or counted collectives (pinned by
   ``tests/test_telemetry.py``);
-* **honest wall-clock** — a span calls ``jax.block_until_ready`` on
-  whatever the caller registered via ``sp.sync(x)`` before reading the
-  clock, so async dispatch does not attribute one phase's device time to
-  the next;
-* **profiler bridge** — ``configure(profiler=True)`` additionally opens a
-  ``jax.profiler.TraceAnnotation`` per span so the same phase names show
-  up in TensorBoard/Perfetto traces.
+* **honest wall-clock** — with a sink installed, a span calls
+  ``jax.block_until_ready`` on whatever the caller registered via
+  ``sp.sync(x)`` before reading the clock, so async dispatch does not
+  attribute one phase's device time to the next;
+* **one clock with the device** — every span always opens a
+  ``jax.profiler.TraceAnnotation`` of its name, so a profiler trace shows
+  the host phases beside the device ops (``annotate`` names the device
+  side).  With no trace active the annotation costs about a microsecond
+  and never syncs.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import time
 from typing import Any
+
+from jax.profiler import TraceAnnotation
 
 from repro.telemetry import events as ev
 from repro.telemetry import sinks as _sinks
@@ -29,7 +34,6 @@ from repro.telemetry import sinks as _sinks
 _SINKS: list[Any] = []
 _COUNTERS: dict[str, float] = {}
 _SPAN_STACK: list[str] = []
-_PROFILER_BRIDGE = False
 
 ENV_TRACE = "REPRO_TRACE"  # path of a JSONL trace to auto-install
 ENV_VERBOSITY = "REPRO_TELEMETRY_VERBOSITY"  # >0: auto console sink
@@ -53,12 +57,6 @@ def sinks() -> tuple:
 def enabled() -> bool:
     """True when at least one sink is installed (spans measure, events land)."""
     return bool(_SINKS)
-
-
-def configure(profiler: bool | None = None) -> None:
-    global _PROFILER_BRIDGE
-    if profiler is not None:
-        _PROFILER_BRIDGE = bool(profiler)
 
 
 def configure_from_env() -> None:
@@ -115,12 +113,12 @@ def reset_counters() -> None:
 class span:
     """Nestable wall-clock span: ``with telemetry.span("pcg") as sp: ...``.
 
-    Disabled (no sinks): ``__enter__`` returns immediately — no clock read,
-    no block, no event.  Enabled: the exit path ``block_until_ready``s
-    whatever was registered with ``sp.sync(x)`` (pass the jit outputs of the
-    timed region) before reading the clock, emits a SpanEvent carrying the
-    slash-joined nesting path, and — when the profiler bridge is on — the
-    region also appears as a ``jax.profiler.TraceAnnotation``.
+    The region always appears in a profiler trace as a
+    ``jax.profiler.TraceAnnotation`` of its name.  Disabled (no sinks):
+    nothing more — no clock read, no block, no event.  Enabled: the exit
+    path ``block_until_ready``s whatever was registered with ``sp.sync(x)``
+    (pass the jit outputs of the timed region) before reading the clock and
+    emits a SpanEvent carrying the slash-joined nesting path.
     """
 
     __slots__ = ("name", "attrs", "wall_s", "_t0", "_sync", "_ta")
@@ -139,19 +137,16 @@ class span:
         return x
 
     def __enter__(self):
-        if not _SINKS:
-            return self
-        if _PROFILER_BRIDGE:
-            import jax
-
-            self._ta = jax.profiler.TraceAnnotation(self.name)
-            self._ta.__enter__()
-        _SPAN_STACK.append(self.name)
-        self._t0 = time.perf_counter()
+        self._ta = TraceAnnotation(self.name)
+        self._ta.__enter__()
+        if _SINKS:
+            _SPAN_STACK.append(self.name)
+            self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         if self._t0 is None:
+            self._ta.__exit__(exc_type, exc, tb)
             return False
         try:
             if exc_type is None and self._sync is not None:
@@ -164,24 +159,32 @@ class span:
         finally:
             if _SPAN_STACK and _SPAN_STACK[-1] == self.name:
                 _SPAN_STACK.pop()
-            if self._ta is not None:
-                self._ta.__exit__(exc_type, exc, tb)
-                self._ta = None
+            self._ta.__exit__(exc_type, exc, tb)
         if exc_type is None:
             emit(ev.SpanEvent(name=self.name, wall_s=self.wall_s, path=path,
                               depth=depth, attrs=self.attrs))
         return False
 
 
+@contextlib.contextmanager
 def annotate(name: str):
-    """Name a region INSIDE traced code: pure HLO-metadata ``named_scope``.
+    """Name a region INSIDE traced code: pure HLO metadata.
 
-    Safe on the hot path — affects op metadata only (profiles and HLO dumps
-    show the phase), never the graph structure, executables, or collectives.
+    Two carriers, both metadata only — never the graph structure,
+    executables, or collectives:
+
+    * ``jax.named_scope(name)``: the ``op_name`` stack of every op (HLO
+      dumps, the profiler's op tables);
+    * the frontend attribute ``<family>="<name>"`` (``family`` is the part
+      of ``name`` before its first dot: ``gn``, ``interp``, ``fft``...),
+      which XLA prints in the instruction's text.  A TPU device trace names
+      each op by that text, so a trace reader finds the phase there.
     """
     import jax
+    from jax.experimental.xla_metadata import set_xla_metadata
 
-    return jax.named_scope(name)
+    with jax.named_scope(name), set_xla_metadata(**{name.split(".", 1)[0]: name}):
+        yield
 
 
 def jsonl_sink(path) -> _sinks.JsonlSink:

@@ -114,6 +114,54 @@ def test_serve_refill_one_executable(cohort_and_singles):
         assert dv / ref < 5e-4, s
 
 
+def test_result_carries_lap2_on_the_device(cohort_and_singles):
+    """A retired job's ``JobResult.lap2``, as ``step`` returns it, is its
+    slot's carried ``Lap^2 v``, left on the device: what the velocity's own
+    transform gives, up to f32 roundoff."""
+    from repro.core.spectral import SpectralOps
+    from repro.launch.reg_serve import CohortServer, RegJob
+
+    grid, rho_R, rho_T, _, _ = cohort_and_singles
+    server = CohortServer(grid, CFG, slots=2)
+    server.admit(*(RegJob(job_id=s, rho_R=rho_R[s], rho_T=rho_T[s]) for s in range(2)))
+    results = []
+    while server.queue or server.active.any():
+        results += server.step()
+    assert len(results) == 2
+    ops = SpectralOps(grid)
+    for r in results:
+        assert isinstance(r.lap2, jax.Array) and r.lap2.shape == r.v.shape
+        want = np.asarray(ops.biharmonic(jnp.asarray(r.v)))
+        err = np.max(np.abs(np.asarray(r.lap2) - want)) / np.max(np.abs(want))
+        assert err < 1e-3, (r.job_id, err)
+
+
+def test_server_device_memory_does_not_grow_with_retired_jobs(cohort_and_singles):
+    """The server's own record of its retired jobs holds no ``Lap^2 v`` on
+    the device: a second drain of the same work leaves at most the bytes of
+    its results' host ``v`` live (which a CPU backend shares with the
+    device buffer it was read from; a chip copies it out)."""
+    import gc
+
+    from repro.launch.reg_serve import CohortServer, RegJob
+
+    grid, rho_R, rho_T, _, _ = cohort_and_singles
+    server = CohortServer(grid, CFG, slots=2)
+    n = rho_R.shape[0]
+
+    def drain_and_count():
+        server.admit(*(RegJob(job_id=s, rho_R=rho_R[s], rho_T=rho_T[s]) for s in range(n)))
+        server.run()
+        gc.collect()
+        return sum(a.nbytes for a in jax.live_arrays())
+
+    first = drain_and_count()
+    grown = drain_and_count() - first
+    assert len(server.results) == 2 * n
+    assert all(r.lap2 is None for r in server.results)
+    assert grown <= sum(r.v.nbytes for r in server.results[n:]), grown
+
+
 def test_retirement_status_splits_converged_from_max_newton(cohort_and_singles):
     """Regression for the JobResult.converged=False conflation: the explicit
     ``status`` field distinguishes a clean convergence from an iteration-cap

@@ -14,8 +14,10 @@ Acceptance pins:
 * ``trace_report`` renders per-phase wall/matvec/collective tables from a
   toy run and its matvec sums match the solver's own meters.
 """
+import glob
 import json
 import os
+import re
 import sys
 
 import jax
@@ -258,6 +260,134 @@ def test_telemetry_does_not_change_mesh_collectives():
         """,
         devices=8,
     )
+
+
+# --------------------------------------------------------------------------- #
+# profiler names: device scopes in the compiled step, host spans in a trace
+# --------------------------------------------------------------------------- #
+# the scopes the benchmark's per-layer readers (bench/metrics/) key on
+SCOPES = ("gn.gradient", "gn.pcg", "gn.line_search", "gn.update", "interp.plan",
+          "interp.apply", "interp.displace", "fft.fwd", "fft.inv")
+SERVE_SPANS = {"serve.step", "serve.fill", "serve.dispatch", "serve.read_log",
+               "serve.retire"}
+
+
+def _step_hlo(kind):
+    """Compiled HLO text of a 16^3 Newton step: the cohort step of the
+    serving path, or the single solve's iteration."""
+    from functools import partial
+
+    from repro.core import objective as obj
+    from repro.core.grid import make_grid
+    from repro.core.spectral import SpectralOps
+
+    grid = make_grid((16,) * 3)
+    cfg = gn.GNConfig(beta=1e-2, n_t=2, max_cg=3, autotune="off")
+    img = jnp.zeros(grid.shape, grid.dtype)
+    if kind == "cohort":
+        v = jnp.zeros((2, 3) + grid.shape, grid.dtype)
+        step = gn.make_cohort_step(grid, cfg)
+        args = (gn.Iterate(v, v), jnp.full((2,), 1e-30, jnp.float32), jnp.ones((2,), bool),
+                jnp.float32(cfg.beta), jnp.stack([img, img]), jnp.stack([img, img]))
+    else:
+        v = jnp.zeros((3,) + grid.shape, grid.dtype)
+        prob = obj.Problem(grid, img, img, cfg.beta, cfg.n_t, False)
+        step = jax.jit(partial(gn.newton_iteration, prob=prob, ops=SpectralOps(grid),
+                               cfg=cfg))
+        args = (gn.Iterate(v, v), jnp.float32(1e-30))
+    return step.lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("kind", ["cohort", "single"])
+def test_newton_step_names_every_scope(kind):
+    """Each scope sits in the op_name stack of the compiled step, and rides
+    its ops as the frontend attribute ``<family>="<name>"`` that a TPU trace
+    prints in the op's name."""
+    hlo = _step_hlo(kind)
+    stacks = [s.split("/") for s in re.findall(r'op_name="([^"]*)"', hlo)]
+    for name in SCOPES:
+        assert any(name in s for s in stacks), name
+        assert f'{name.split(".")[0]}="{name}"' in hlo, name
+    # the four phases split the step: no op sits in two of them
+    phases = [p for p in SCOPES if p.startswith("gn.")]
+    assert not any(sum(p in s for p in phases) > 1 for s in stacks)
+
+
+def test_multilevel_transfer_names_its_transforms():
+    """The grid transfers run their transforms under the same ``fft.*``
+    scopes as ``SpectralOps``' own."""
+    from repro.core.grid import make_grid
+    from repro.core.spectral import SpectralOps
+    from repro.multilevel import transfer
+
+    fine, coarse = SpectralOps(make_grid((16,) * 3)), SpectralOps(make_grid((8,) * 3))
+    f = jnp.zeros((16,) * 3, jnp.float32)
+    g = jnp.zeros((8,) * 3, jnp.float32)
+    for fn, args in ((transfer.restrict, (f, fine, coarse)),
+                     (transfer.prolong, (g, coarse, fine)),
+                     (transfer.smooth_restrict, (f, fine, coarse))):
+        hlo = jax.jit(fn, static_argnums=(1, 2)).lower(*args).compile().as_text()
+        for name in ("fft.fwd", "fft.inv"):
+            assert f'fft="{name}"' in hlo, (fn.__name__, name)
+
+
+def _host_event_names(trace_dir):
+    from jax.profiler import ProfileData
+
+    path = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"), recursive=True)
+    prof = ProfileData.from_file(max(path, key=os.path.getmtime))
+    return {ev.name for plane in prof.planes if plane.name == "/host:CPU"
+            for line in plane.lines for ev in line.events}
+
+
+def test_serve_step_spans_reach_a_profiler_trace(tmp_path):
+    """With no sink installed, one ``CohortServer.step`` leaves its serve
+    phases on the host plane of a profiler trace."""
+    from repro.launch.reg_serve import CohortServer, RegJob
+
+    probs = [synthetic_problem(8, n_t=2, amplitude=a) for a in (0.4, 0.8)]
+    cfg = gn.GNConfig(beta=1e-2, n_t=2, max_newton=4, max_cg=5, gtol=1e-2)
+    server = CohortServer(probs[0][3], cfg, slots=1)
+    server.admit(*(RegJob(job_id=i, rho_R=p[0], rho_T=p[1]) for i, p in enumerate(probs)))
+    server.step()  # compiled outside the trace
+    assert not telemetry.enabled()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        server.step()
+    finally:
+        jax.profiler.stop_trace()
+    assert SERVE_SPANS <= _host_event_names(tmp_path)
+
+
+def test_span_without_sink_neither_syncs_nor_emits(monkeypatch):
+    synced = []
+    monkeypatch.setattr(jax, "block_until_ready", lambda x: synced.append(x) or x)
+    with telemetry.span("serve.step") as sp:
+        sp.sync(jnp.ones(3))
+    assert sp.wall_s is None and synced == []
+    sink = telemetry.ListSink()
+    with sink:
+        with telemetry.span("serve.step") as sp:
+            sp.sync(jnp.ones(3))
+    assert len(synced) == 1 and sp.wall_s is not None
+    assert [r["kind"] for r in sink.records] == ["span"]
+
+
+def test_cohort_one_executable_under_a_profiler_trace(tmp_path):
+    probs = [synthetic_problem(8, n_t=2, amplitude=a) for a in (0.4, 1.0)]
+    grid = probs[0][3]
+    rho_R = jnp.stack([p[0] for p in probs])
+    rho_T = jnp.stack([p[1] for p in probs])
+    cfg = gn.GNConfig(beta=1e-2, n_t=2, max_newton=3, max_cg=5, gtol=1e-2)
+    off = gn.solve_cohort(rho_R, rho_T, grid, cfg)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        on = gn.solve_cohort(rho_R, rho_T, grid, cfg)
+    finally:
+        jax.profiler.stop_trace()
+    assert off["compiled_executables"] == on["compiled_executables"] == 1
+    assert list(on["hessian_matvecs"]) == list(off["hessian_matvecs"])
+    assert "gn.cohort_iter" in _host_event_names(tmp_path)
 
 
 # --------------------------------------------------------------------------- #

@@ -133,3 +133,30 @@ def test_newton_step_on_2x2_mesh_compiles(topo, on_tpu):
     compiled = _compile(step, *args)
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo and "all-to-all" in hlo
+
+
+def test_cohort_step_scopes_reach_the_chip_program(one_chip, on_tpu):
+    """The named scopes ride the chip program's ops as frontend attributes
+    (what a TPU trace prints in an op's name), and the kernels keep their
+    instruction names under them."""
+    grid = make_grid((16, 16, 128))
+    cfg = gn.GNConfig(autotune="off")
+    step = gn.make_cohort_step(grid, cfg)
+
+    def sds(s, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    v = sds((2, 3) + grid.shape)
+    img = sds((2,) + grid.shape)
+    hlo = step.lower(gn.Iterate(v, v), sds((2,)), sds((2,), jnp.bool_), sds(()), img,
+                     img).compile().as_text()
+    for name in ("gn.gradient", "gn.pcg", "gn.line_search", "gn.update"):
+        assert f'gn="{name}"' in hlo, name
+    kernels = [line for line in hlo.splitlines()
+               if line.lstrip().startswith(("%tricubic_apply_pallas_padded",
+                                            "%tricubic_displace_pallas_padded_many"))]
+    assert {k.split(".")[0].strip() for k in kernels} == {
+        "%tricubic_apply_pallas_padded", "%tricubic_displace_pallas_padded_many"}
+    for k in kernels:
+        scope = "interp.apply" if "apply" in k.split("=")[0] else "interp.displace"
+        assert f'interp="{scope}"' in k and 'gn="gn.' in k, k
