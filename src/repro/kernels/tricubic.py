@@ -22,13 +22,26 @@ of exactly that blocking:
     a loop over window planes.  Every query of a (T2, T3) slice is one
     lane-dense vreg tile, so the whole contraction is exact f32 VPU work
     with no gathers, reshapes or matmul precision loss.
+  * Each iteration of a sweep loop costs a latency far above its vector
+    work, so the x2 and x3 sweeps run in fixed-width chunks unrolled in the
+    trace: ``ROW_CHUNK`` window rows at a time, and for them ``LANE_CHUNK``
+    x3 offsets at a time.  A lane chunk rotates the rows' two lane halves
+    once by its dynamic base; each of its offsets is then one select and
+    one static rotate, accumulated into ``LANE_ACCS`` independent partial
+    sums.  The rows and offsets a last chunk adds past the slice's reach
+    weigh 0.
 
 Layout: the output tile is (T1, T2, T3) with T3 on lanes; on the chip
 ``T2 % 8 == 0`` and ``T3 % 128 == 0`` (``kernels.ops.pick_tile``).  The VMEM
 scratch holds the ghost-extended window ``(C, T1+R-1, T2+R-1, L3)`` with
-``L3 = T3+R-1`` rounded up to whole lanes — about 0.5 MB per channel at the
-default (8, 8, 128) tile and H=4.  Fields and plan weights are widened to
-f32 once on entry; the result is cast back to the field dtype.
+``L3 = T3+R-1`` rounded up to whole lanes, and T2+R-1 to whole sublanes.
+The solver runs a ghost budget of 8 at 128^3 (R = 20): at the (8, 8, 128)
+tile the window is (C, 27, 32, 256) f32, about 0.88 MB per channel (16 on
+a 256^3 shard: (C, 43, 48, 256), 2.1 MB).  A second scratch holds the
+one-hot planes of axes 2 and 3, (2, R-1+LANE_CHUNK, T2, T3) f32: 0.29 MB at
+budget 8, with the LANE_CHUNK-1 zero planes of the chunk tails.  Fields and
+plan weights are widened to f32 once on entry; the result is cast back to
+the field dtype.
 """
 from __future__ import annotations
 
@@ -42,6 +55,10 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.ref import InterpPlan, lagrange_weights
 
 SUBLANES, LANES = 8, 128
+# the x3 offsets and the x2 rows each loop iteration of the contraction
+# sweeps, unrolled, and the independent partial sums a channel keeps over a
+# row chunk's lane sweep (chosen on a v5e, PERF.md)
+LANE_CHUNK, LANE_ACCS, ROW_CHUNK = 16, 2, 4
 
 
 def _round_up(n, m):
@@ -65,37 +82,74 @@ def _contract_slice(scratch, planes, out_ref, s1, rel, wts, *, tile, halo):
     Only the offsets some query of the slice reaches are swept: a smooth
     displacement spans a few offsets per axis, so the cost follows the
     slice's spread of stencil bases, not the halo budget.
+
+    The x2 and x3 sweeps run in chunks unrolled in the trace, so that one
+    loop iteration carries much independent work and its latency is paid
+    once: ``ROW_CHUNK`` rows of the window plane at a time, and for them
+    ``LANE_CHUNK`` x3 offsets at a time.  A lane chunk rotates the rows' two
+    lane halves once by its dynamic base; each of its offsets is then one
+    select and one static rotate, summed into ``LANE_ACCS`` independent
+    partials per channel.  Rows and offsets past the slice's last weigh 0
+    (the zeroed tail planes).
     """
     _, t2, t3 = tile
     r = 2 * halo + 4
     c, w2, lanes = scratch.shape[0], scratch.shape[2], scratch.shape[3]
+    half = lanes // 2
+    # a chunk's shifted rows read lanes up to t3 - 1 + (r - 1) + (LANE_CHUNK - 1)
+    assert t3 <= half and t3 + r + LANE_CHUNK - 3 < lanes, (tile, halo, lanes)
+    assert ROW_CHUNK <= LANE_CHUNK  # the zero tail below covers both chunks
+    zero = jnp.zeros((t2, t3), jnp.float32)
     for o in range(r):  # one-hot planes of axes 2 and 3, indexed dynamically
         planes[0, o] = _onehot_plane(rel[1], wts[1], o)
         planes[1, o] = _onehot_plane(rel[2], wts[2], o)
+    for o in range(r, r + LANE_CHUNK - 1):
+        planes[0, o] = zero
+        planes[1, o] = zero
     lo = [jnp.min(x) for x in rel]
     hi = [jnp.max(x) + 4 for x in rel]
-    zero = jnp.zeros((t2, t3), jnp.float32)
+    n_lane = (hi[2] - lo[2] + LANE_CHUNK - 1) // LANE_CHUNK
+    n_row = (hi[1] - lo[1] + ROW_CHUNK - 1) // ROW_CHUNK
+    lane = jax.lax.broadcasted_iota(jnp.int32, (t2, half), 1)
+    rzero = jnp.zeros((ROW_CHUNK, t2, t3), jnp.float32)
 
     def plane_a(a, acc):
         slabs = [scratch[ch, s1 + a] for ch in range(c)]  # (W2, L3)
 
-        def row_b(b, h):
-            # rows b .. b+T2 of the window plane: a sublane rotation (loads
-            # at a dynamic unaligned sublane offset do not lower)
-            shift = (w2 - b) % w2
-            rows = [pltpu.roll(x, shift, 0)[:t2] for x in slabs]
+        def row_chunk(j, h):
+            b = lo[1] + j * ROW_CHUNK
+            # rows b+v .. b+v+T2 of the window plane, v < ROW_CHUNK: sublane
+            # rotations (loads at a dynamic unaligned sublane offset do not lower)
+            rows = [jnp.stack([pltpu.roll(x, (w2 - b - v) % w2, 0)[:t2]
+                               for v in range(ROW_CHUNK)]) for x in slabs]
 
-            def lane_o(o3, g):
-                w = planes[1, o3]
-                shift = (lanes - o3) % lanes
-                return tuple(g[ch] + w * pltpu.roll(rows[ch], shift, 1)[:, :t3]
-                             for ch in range(c))
+            def lane_chunk(k, g):
+                base = lo[2] + k * LANE_CHUNK
+                # lanes base .. base + half of the rows, and the half after them
+                shift, low = (half - base) % half, lane < half - base
+                shifted = []
+                for x in rows:
+                    x0 = pltpu.roll(x[..., :half], shift, 2)
+                    x1 = pltpu.roll(x[..., half:], shift, 2)
+                    shifted.append((jnp.where(low, x0, x1), jnp.where(low, x1, x0)))
+                g = list(g)
+                for u in range(LANE_CHUNK):
+                    w = planes[1, base + u]
+                    for ch, (x0, x1) in enumerate(shifted):
+                        if u:  # x1 fills the u lanes the rotate wraps round
+                            x0 = pltpu.roll(jnp.where(lane >= u, x0, x1), half - u, 2)
+                        i = ch * LANE_ACCS + u % LANE_ACCS
+                        g[i] = g[i] + w * x0[..., :t3]
+                return tuple(g)
 
-            g = jax.lax.fori_loop(lo[2], hi[2], lane_o, (zero,) * c)
-            w = planes[0, b]
-            return tuple(h[ch] + w * g[ch] for ch in range(c))
+            g = jax.lax.fori_loop(0, n_lane, lane_chunk, (rzero,) * (c * LANE_ACCS))
+            out = []
+            for ch in range(c):
+                gc = sum(g[ch * LANE_ACCS + 1:(ch + 1) * LANE_ACCS], g[ch * LANE_ACCS])
+                out.append(sum((planes[0, b + v] * gc[v] for v in range(ROW_CHUNK)), h[ch]))
+            return tuple(out)
 
-        h = jax.lax.fori_loop(lo[1], hi[1], row_b, (zero,) * c)
+        h = jax.lax.fori_loop(0, n_row, row_chunk, (zero,) * c)
         w = _onehot_plane(rel[0], wts[0], a)
         return tuple(acc[ch] + w * h[ch] for ch in range(c))
 
@@ -177,7 +231,7 @@ def _pallas(kern, fpad, operands, *, tile, halo, interpret):
         out_shape=jax.ShapeDtypeStruct((c, n1, n2, n3), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((c,) + window, jnp.float32),
-            pltpu.VMEM((2, pad + 1, t2, t3), jnp.float32),
+            pltpu.VMEM((2, pad + LANE_CHUNK, t2, t3), jnp.float32),
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,

@@ -76,3 +76,32 @@ def single_mesh():
     from repro.launch.mesh import make_mesh
 
     return make_mesh((1,), ("data",))
+
+
+def smooth_disp(shape, amps, edge=None):
+    """A smooth displacement (3, N1, N2, N3) in grid units for the tricubic
+    kernel's parity tests.  Axes 0 and 1 vary mostly along x1, so they are
+    nearly constant over one output slice of the kernel, axis 1 spreading
+    over five rows; axis 2 varies along x3, so ``amps[2]`` sets the width of
+    the lane (x3) sweep.  With
+    ``edge`` the field is clipped to +-edge, which it then reaches: a
+    departure point at the rim of the kernel's window."""
+    x1, x2, x3 = (2 * np.pi * np.arange(n) / n for n in shape)
+    x1, x2, x3 = x1[:, None, None], x2[None, :, None], x3[None, None, :]
+    d = np.stack(np.broadcast_arrays(
+        amps[0] * np.sin(x1 + 0.4),
+        amps[1] * np.cos(x1 + 0.3) + 0.4 * np.sin(x2),
+        amps[2] * np.sin(x3 + 0.5) + 0.3 * np.cos(x2),
+    ))
+    if edge is not None:
+        d = np.clip(d, -edge, edge)
+    return d.astype(np.float32)
+
+
+def sweep_widths(disp, tile, axis):
+    """Window offsets each (T2, T3) output slice of the kernel sweeps along
+    ``axis``: the spread of its stencil bases plus the 4 stencil points."""
+    _, t2, t3 = tile
+    n1, n2, n3 = disp.shape[1:]
+    ib = np.floor(disp[axis]).reshape(n1, n2 // t2, t2, n3 // t3, t3)
+    return (ib.max(axis=(2, 4)) - ib.min(axis=(2, 4)) + 4).astype(int)

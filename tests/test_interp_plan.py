@@ -13,6 +13,7 @@ import os
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import smooth_disp, sweep_widths
 
 from repro.core import objective as obj
 from repro.core import semilag
@@ -22,7 +23,12 @@ from repro.core.spectral import SpectralOps
 from repro.data import synthetic
 from repro.kernels import ops as kops
 from repro.kernels import ref
-from repro.kernels.tricubic import tricubic_apply_pallas, tricubic_displace_pallas_many
+from repro.kernels.tricubic import (
+    LANE_CHUNK,
+    ROW_CHUNK,
+    tricubic_apply_pallas,
+    tricubic_displace_pallas_many,
+)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -92,9 +98,29 @@ def test_pallas_batched_matches_ref(rng, c):
     np.testing.assert_allclose(out, _looped(f, d), atol=1e-4, rtol=1e-4)
 
 
-def test_pallas_planned_matches_ref(rng):
-    shape, tile, halo = (16, 16, 32), (8, 8, 16), 4
-    f, d = _problem(rng, shape, c=3, lim=halo - 0.1)
+@pytest.mark.parametrize("halo,amps,edge,c", [
+    pytest.param(4, None, False, 3, id="4-uniform-C3"),
+    # smooth fields at the solver's budgets (row and lane sweeps not whole
+    # chunks), and fields reaching the rim of the kernel's window
+    pytest.param(8, (2.0, 1.5, 3.3), False, 2, id="8-smooth-C2"),
+    pytest.param(8, (10.4, 10.4, 10.4), True, 1, id="8-edge-C1"),
+    pytest.param(16, (3.0, 2.0, 7.0), False, 1, id="16-smooth-C1"),
+    pytest.param(16, (20.8, 20.8, 20.8), True, 2, id="16-edge-C2"),
+])
+def test_pallas_planned_matches_ref(rng, halo, amps, edge, c):
+    if amps is None:
+        shape, tile = (16, 16, 32), (8, 8, 16)
+        f, d = _problem(rng, shape, c=c, lim=halo - 0.1)
+    else:
+        # T3 is half the staged row, as on the chip, and the row does not
+        # repeat with period T3: each lane half is read for what it holds
+        shape, tile = (16, 8, 128), (8, 8, 64)
+        d = smooth_disp(shape, amps, edge=halo if edge else None)
+        assert np.any(sweep_widths(d, tile, 2) % LANE_CHUNK)
+        assert np.any(sweep_widths(d, tile, 1) % ROW_CHUNK)
+        assert (np.max(np.abs(d)) == halo) == edge
+        f = jnp.asarray(rng.standard_normal((c,) + shape), jnp.float32)
+        d = jnp.asarray(d)
     plan = ref.make_interp_plan(d)
     out = tricubic_apply_pallas(f, plan, tile=tile, halo=halo, interpret=True)
     np.testing.assert_allclose(out, _looped(f, d), atol=1e-4, rtol=1e-4)

@@ -73,7 +73,7 @@ KERNEL_CASES = [((128, 128, 128), 8), ((128, 128, 256), 16)]
 
 
 @pytest.mark.parametrize("shape,halo", KERNEL_CASES)
-@pytest.mark.parametrize("c", [1, 3])
+@pytest.mark.parametrize("c", [1, 2, 3])
 @pytest.mark.parametrize("kind", ["planned", "displace"])
 def test_pallas_kernel_compiles(one_chip, on_tpu, shape, halo, c, kind):
     rs = kops.resolve("pallas", shape)
